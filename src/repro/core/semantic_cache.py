@@ -73,6 +73,12 @@ from repro.kernels.cosine_topk.ops import quantize_rows
 # rare dense fallbacks), so it is set generously.
 QUANT_SLACK = 1e-3
 
+# Every f32 lookup contraction runs at full f32 precision. On a TPU v5e the
+# default f32 matmul is one bf16 pass: unit-vector sims at dim 768 were off
+# by up to 4e-4 against float64, enough to flip hit decisions near theta_R;
+# at HIGHEST by 2.5e-8. On CPU, f32 is exact either way.
+LOOKUP_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def _lane_pad(d: int) -> int:
     """Lane-width (128) padded feature dim for device mirrors."""
@@ -87,7 +93,8 @@ def _fused_top1(queries: jax.Array, mat: jax.Array, ans: jax.Array,
     queries (B, D) x mat (pad, D) -> per query: best sim, its row, the hit
     mask at theta_R, and the gathered answer/answer_id (zero / -1 on miss).
     """
-    sims = queries @ mat.T                                   # (B, pad)
+    sims = jnp.matmul(queries, mat.T,
+                      precision=LOOKUP_PRECISION)            # (B, pad)
     sims = jnp.where(valid[None, :], sims, -1.0)
     idx = jnp.argmax(sims, axis=1)
     best = jnp.take_along_axis(sims, idx[:, None], axis=1)[:, 0]
@@ -141,7 +148,7 @@ def _rescore_mm(queries: jax.Array, mat: jax.Array) -> jax.Array:
     *other* rows share the matmul, so rescoring a gathered row subset
     reproduces the f32 reference similarities bit for bit.
     """
-    return queries @ mat.T
+    return jnp.matmul(queries, mat.T, precision=LOOKUP_PRECISION)
 
 
 @dataclass

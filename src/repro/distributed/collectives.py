@@ -59,9 +59,8 @@ def sharded_topk(queries: jax.Array, centroids: jax.Array, k: int,
 
     spec_q = P()                      # queries replicated over the axis
     spec_c = P(axis, None)
-    from repro.compat import shard_map
-    fn = shard_map(kern, mesh=mesh, in_specs=(spec_q, spec_c),
-                   out_specs=(P(), P()))
+    fn = jax.shard_map(kern, mesh=mesh, in_specs=(spec_q, spec_c),
+                       out_specs=(P(), P()), check_vma=False)
     return fn(queries, centroids)
 
 
@@ -86,8 +85,7 @@ def cross_shard_top1(best: jax.Array, host_row: jax.Array,
     answer_id) with the fused theta compare + answer gather applied
     (zeros / -1 on miss).
     """
-    from repro.compat import axis_size
-    world = axis_size(axis)
+    world = jax.lax.axis_size(axis)
     bg = jax.lax.all_gather(best, axis, axis=1)          # (B, world)
     rg = jax.lax.all_gather(host_row, axis, axis=1)      # (B, world)
     m = jnp.max(bg, axis=1)
@@ -114,8 +112,7 @@ def ring_allreduce_schedule(x: jax.Array, axis: str) -> jax.Array:
     """Reduce-scatter + all-gather ring via collective_permute (inside
     shard_map). Equivalent to psum; exists so the schedule is explicit and
     each hop can be interleaved with compute by the caller."""
-    from repro.compat import axis_size
-    world = axis_size(axis)
+    world = jax.lax.axis_size(axis)
     if world == 1:
         return x
     perm = [(i, (i + 1) % world) for i in range(world)]

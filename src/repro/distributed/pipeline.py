@@ -64,11 +64,11 @@ def pipeline_forward(stage_fn: Callable, params_stacked, x: jax.Array,
                                     jnp.arange(M + S - 1))
         return outs
 
-    from repro.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         kern, mesh=mesh,
         in_specs=(P(axis), P()),       # params stage-sharded; batch replicated
-        out_specs=P(axis))             # (S*M, b, ...): per-stage out buffers
+        out_specs=P(axis),             # (S*M, b, ...): per-stage out buffers
+        check_vma=False)
     outs = fn(params_stacked, micro)
     outs = outs.reshape(S, M, B // M, *x.shape[1:])[-1]   # last stage's
     return outs.reshape(B, *x.shape[1:])

@@ -89,8 +89,7 @@ def param_spec(path, leaf, cfg: ModelConfig, fsdp: bool,
 
 def param_specs(params, cfg: ModelConfig, fsdp: bool,
                 expert_data: bool = False, fsdp_axes: tuple = ("data",)):
-    from repro.compat import tree_map_with_path
-    return tree_map_with_path(
+    return jax.tree.map_with_path(
         lambda path, leaf: param_spec(path, leaf, cfg, fsdp, expert_data,
                                       fsdp_axes),
         params)

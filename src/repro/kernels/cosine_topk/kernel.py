@@ -22,6 +22,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 NEG = float("-inf")
+# f32 tile dots at full f32 precision on the MXU (several bf16 passes). At
+# the default single bf16 pass, sims at dim 768 on a TPU v5e were off by up
+# to 2.7e-4 against float64 (2.8e-8 at HIGHEST): enough to flip theta_R
+# decisions against the dense path and an exact reference.
+PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _merge_topk(run_vals, run_idx, sims, idx, k: int):
@@ -70,7 +75,7 @@ def cosine_topk_kernel(theta_ref, q_ref, c_ref, valid_ref, vals_ref, idx_ref,
         q = q_ref[...]
         c = c_ref[...]
         sims = jax.lax.dot_general(
-            q, c, (((1,), (1,)), ((), ())),
+            q, c, (((1,), (1,)), ((), ())), precision=PRECISION,
             preferred_element_type=jnp.float32)              # (B, Ct)
         v = valid_ref[...]                                   # (1, Ct)
         sims = jnp.where(v != 0, sims, NEG)
@@ -128,7 +133,7 @@ def cosine_topk_q8_kernel(tm_ref, q_ref, c_ref, s_ref, valid_ref, vals_ref,
         q = q_ref[...]
         c = c_ref[...].astype(jnp.float32)                   # dequant widen
         sims = jax.lax.dot_general(
-            q, c, (((1,), (1,)), ((), ())),
+            q, c, (((1,), (1,)), ((), ())), precision=PRECISION,
             preferred_element_type=jnp.float32)              # (B, Ct)
         sims = sims * s_ref[...]                             # per-row scale
         v = valid_ref[...]                                   # (1, Ct)

@@ -27,6 +27,13 @@ Three modes (``--mode``, DESIGN.md §16.3):
   PYTHONPATH=src python -m repro.launch.serve --mode replica --replicas 3
   PYTHONPATH=src python -m repro.launch.serve --mode replica \
       --transport socket --replicas 3   # one process per replica
+  PYTHONPATH=src python -m repro.launch.serve --mode http \
+      --arch minicpm3-4b --dim 768 --no-reduced   # published widths
+
+Models are built at toy width unless ``--no-reduced`` is given. On a TPU
+host ``--transport socket`` is refused: only one process may hold a chip,
+so the replicas run in one process with ``--transport inproc``. Every mode
+keeps JAX's persistent compilation cache (``enable_compile_cache``).
 
 Port layout in socket mode (base = ``--port``): the router listens on
 base, worker i's HTTP front end on base+1+i, worker i's replication
@@ -36,6 +43,8 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
+import pathlib
 import signal
 import subprocess
 import sys
@@ -44,10 +53,13 @@ import time
 import urllib.error
 import urllib.request
 import zlib
+from functools import partial
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional, Sequence
 
 import numpy as np
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
 
 # region int8 -> header tag (LookupResult.region, DESIGN.md §13/§14)
 REGION_NAMES = {-1: "miss", 0: "centroid", 1: "spill", 2: "warm",
@@ -64,6 +76,35 @@ def user_key(user) -> Optional[int]:
         return int(user)
     except (TypeError, ValueError):
         return zlib.crc32(str(user).encode()) & 0x7FFFFFFF
+
+
+def enable_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache for an entry point and
+    return its directory. ``JAX_COMPILATION_CACHE_DIR``, where set, is
+    what JAX already uses, and nothing else is set; otherwise the cache is
+    ``<repo>/.jax_cache``, a fixed path in the checkout, so repeated runs
+    of the same checkout find their compiled programs again. Entry points
+    call this; library modules and tests never do."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def tpu_attached() -> bool:
+    """Whether JAX would get a TPU here, decided without initialising a
+    backend: a process that initialises one holds the chip, and a child
+    that needs it then fails. An explicit ``JAX_PLATFORMS`` without
+    ``tpu`` rules it out; otherwise the PCI bus is scanned for TPU chips
+    the way JAX's own TPU start-up does."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return False
+    from jax._src import hardware_utils
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0] > 0
 
 
 def hash_embed_fn(dim: int):
@@ -359,15 +400,28 @@ def _serving_config(args) -> "ServingConfig":
         slo_latency=args.slo, llm_latency=args.slo / 1.3)
 
 
-def _make_engine(args):
-    import jax
+def _model_config(args):
+    """The engine's architecture: published widths, or the toy-width
+    variant with ``--reduced`` (the default, for CPU runs)."""
     from repro.configs.base import get_config
+    cfg = get_config(args.arch)
+    return (cfg.reduced() if args.reduced else cfg).replace(remat=False)
+
+
+def init_weights(cfg, seed: int):
+    """Random weights from ``seed``, drawn under jit: eagerly, every stacked
+    leaf is first drawn in f32 over all layers before the cast — at
+    published widths a multi-GB temporary per leaf."""
+    import jax
     from repro.models import lm
+    return jax.jit(partial(lm.init_params, cfg=cfg))(jax.random.PRNGKey(seed))
+
+
+def _make_engine(args):
     from repro.serving.engine import ModelEngine
-    cfg = get_config(args.arch).reduced().replace(remat=False)
-    params = lm.init_params(jax.random.PRNGKey(args.seed), cfg)
-    return ModelEngine(params, cfg, n_slots=args.slots,
-                       max_len=128), cfg
+    cfg = _model_config(args)
+    return ModelEngine(init_weights(cfg, args.seed), cfg,
+                       n_slots=args.slots, max_len=128), cfg
 
 
 def run_http(args) -> int:
@@ -495,7 +549,8 @@ def _run_socket_parent(args) -> int:
             "--arch", args.arch, "--dim", str(args.dim),
             "--capacity", str(args.capacity), "--slots", str(args.slots),
             "--refresh-min", str(args.refresh_min),
-            "--slo", str(args.slo), "--seed", str(args.seed)]
+            "--slo", str(args.slo), "--seed", str(args.seed),
+            "--reduced" if args.reduced else "--no-reduced"]
     if args.no_dta:
         base.append("--no-dta")
     procs = [subprocess.Popen(base + ["--worker-index", str(i)])
@@ -534,16 +589,14 @@ def _run_socket_parent(args) -> int:
 def run_batch(args) -> int:
     """The original one-shot driver (analytic study + real engine pass),
     constructed through the ServingConfig builders."""
-    import jax
     from repro.configs.base import get_config
     from repro.data.synth import SyntheticWorkload
-    from repro.models import lm
     from repro.serving.engine import AnalyticEngine, EngineModel, ModelEngine
     from repro.serving.scheduler import ContinuousBatchScheduler, Request
     from repro.serving.simulator import (ServingSimulator, bootstrap_frontend,
                                          build_system)
 
-    cfg = get_config(args.arch).reduced().replace(remat=False)
+    cfg = _model_config(args)
     wl = SyntheticWorkload(args.profile, dim=args.dim, n_clusters=500,
                            seed=args.seed)
     model = EngineModel.from_config(get_config(args.arch), n_chips=8)
@@ -570,9 +623,9 @@ def run_batch(args) -> int:
           f"e2e={r.mean_e2e:.3f}s quality={r.mean_quality:.3f} "
           f"theta_R(final)={r.theta_trace[-1] if r.theta_trace else None}")
 
-    # --- online path B: real reduced model through continuous batching ---
-    params = lm.init_params(jax.random.PRNGKey(args.seed), cfg)
-    engine = ModelEngine(params, cfg, n_slots=args.slots, max_len=128)
+    # --- online path B: the real model through continuous batching ---
+    engine = ModelEngine(init_weights(cfg, args.seed), cfg,
+                         n_slots=args.slots, max_len=128)
     sched = ContinuousBatchScheduler(engine, cache=siso)
     rng = np.random.default_rng(args.seed)
     n_real = min(args.requests, 32)
@@ -594,12 +647,15 @@ def run_batch(args) -> int:
     return 0
 
 
-def main(argv: Optional[Sequence[str]] = None) -> int:
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=("batch", "http", "replica"),
                     default="batch")
     ap.add_argument("--arch", default="qwen3-14b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="toy-width model (default); --no-reduced builds "
+                         "the architecture at its published widths")
     ap.add_argument("--profile", default="quora")
     ap.add_argument("--requests", type=int, default=200)
     ap.add_argument("--history", type=int, default=3000)
@@ -621,7 +677,18 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     help=argparse.SUPPRESS)   # internal: socket worker
     ap.add_argument("--refresh-min", type=int, default=32)
     ap.add_argument("--slo", type=float, default=1.0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    args = parse_args(argv)
+    if args.mode == "replica" and args.transport == "socket" \
+            and tpu_attached():
+        raise SystemExit(
+            "--transport socket starts one process per replica, and only "
+            "one process may hold a TPU chip; use --transport inproc, whose "
+            "replicas share one engine in this process")
+    enable_compile_cache()
     if args.mode == "batch":
         return run_batch(args)
     return run_http(args)

@@ -7,10 +7,12 @@ L2 normalization — the embedding model SISO uses for queries (Table 1).
 """
 from __future__ import annotations
 
-from typing import Any
+from functools import partial
+from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.configs.siso_embedder import EMBED_FACTOR_DIM
@@ -59,3 +61,28 @@ def encode(p: Params, cfg: ModelConfig, tokens: jax.Array,
         jnp.sum(w, axis=1), 1.0)
     return pooled / jnp.maximum(
         jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-9)
+
+
+def make_embed_fn(p: Params, cfg: ModelConfig, seq_len: int, batch: int
+                  ) -> Callable[[Sequence[np.ndarray]], np.ndarray]:
+    """The gateway's ``embed_fn`` over this encoder: a list of token arrays
+    -> (n, d) unit f32 vectors. ``encode`` runs jitted at one fixed
+    (batch, seq_len) bucket, so serving compiles it once: sequences are cut
+    or zero-padded to ``seq_len`` (0 is the pad id) and the list is
+    encoded ``batch`` rows at a time, the last chunk zero-padded."""
+    enc = jax.jit(partial(encode, cfg=cfg))
+
+    def embed(token_lists: Sequence[np.ndarray]) -> np.ndarray:
+        n = len(token_lists)
+        out = np.zeros((n, cfg.d_model), np.float32)
+        for s in range(0, n, batch):
+            chunk = token_lists[s:s + batch]
+            toks = np.zeros((batch, seq_len), np.int32)
+            for i, t in enumerate(chunk):
+                t = np.asarray(t, np.int32)[:seq_len]
+                toks[i, :len(t)] = t
+            out[s:s + len(chunk)] = np.asarray(
+                enc(p, tokens=jnp.asarray(toks)))[:len(chunk)]
+        return out
+
+    return embed

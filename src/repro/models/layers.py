@@ -562,10 +562,10 @@ def moe_apply_shard_map(p: Params, cfg, x: jax.Array
         pspecs["shared"] = {k: (P(None, "model") if k in ("w_gate", "w_up")
                                 else P("model", None))
                             for k in p["shared"]}
-    from repro.compat import shard_map
-    fn = shard_map(kern, mesh=mesh,
-                   in_specs=(pspecs, P(dp_ax, None, None)),
-                   out_specs=(P(dp_ax, None, None), P()))
+    fn = jax.shard_map(kern, mesh=mesh,
+                       in_specs=(pspecs, P(dp_ax, None, None)),
+                       out_specs=(P(dp_ax, None, None), P()),
+                       check_vma=False)
     return fn(p, x)
 
 

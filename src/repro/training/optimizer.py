@@ -76,8 +76,7 @@ def apply_updates(params, grads, state: AdamWState, cfg: AdamWConfig
     b1c = 1 - cfg.b1 ** step.astype(jnp.float32)
     b2c = 1 - cfg.b2 ** step.astype(jnp.float32)
 
-    from repro.compat import tree_flatten_with_path
-    flat_p, treedef = tree_flatten_with_path(params)
+    flat_p, treedef = jax.tree.flatten_with_path(params)
     flat_g = jax.tree.leaves(grads)
     flat_m = jax.tree.leaves(state.m)
     flat_v = jax.tree.leaves(state.v)

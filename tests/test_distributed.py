@@ -29,7 +29,7 @@ def run_with_devices(code: str, n: int = 8) -> str:
 
 
 def test_param_specs_cover_all_leaves():
-    from repro.compat import tree_flatten_with_path
+    import jax
     from repro.configs.base import get_config
     from repro.distributed import sharding as shd
     from repro.launch.steps import params_struct
@@ -39,8 +39,8 @@ def test_param_specs_cover_all_leaves():
         ps = params_struct(cfg)
         specs = shd.param_specs(ps, cfg, fsdp=True)
         for (path, leaf), (_, spec) in zip(
-                tree_flatten_with_path(ps)[0],
-                tree_flatten_with_path(specs)[0]):
+                jax.tree.flatten_with_path(ps)[0],
+                jax.tree.flatten_with_path(specs)[0]):
             assert len([a for a in spec if a is not None]) <= leaf.ndim
 
 
@@ -59,9 +59,8 @@ def test_moe_expert_rule_divisibility():
             combos.append((False, True))
         for fsdp, ed in combos:
             from jax.sharding import PartitionSpec
-            from repro.compat import tree_flatten_with_path
             specs = shd.param_specs(ps, cfg, fsdp=fsdp, expert_data=ed)
-            flat_l = tree_flatten_with_path(ps)[0]
+            flat_l = jax.tree.flatten_with_path(ps)[0]
             flat_s = jax.tree.leaves(
                 specs, is_leaf=lambda x: isinstance(x, PartitionSpec))
             for (path, leaf), spec in zip(flat_l, flat_s):
@@ -236,13 +235,13 @@ def test_ring_allreduce_matches_psum():
     code = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.distributed.collectives import ring_allreduce_schedule
 mesh = jax.make_mesh((8,), ("x",))
 data = np.arange(8 * 5, dtype=np.float32).reshape(8, 5)
 def kern(x):
     return ring_allreduce_schedule(x[0], "x")
-fn = shard_map(kern, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+fn = jax.shard_map(kern, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+                   check_vma=False)
 out = np.asarray(fn(data)).reshape(8, 5)
 expect = data.sum(axis=0)
 for r in range(8):
@@ -280,14 +279,14 @@ def test_compressed_psum_close_to_exact():
     code = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.distributed.compression import compressed_psum
 mesh = jax.make_mesh((8,), ("data",))
 rng = np.random.default_rng(0)
 g = rng.normal(size=(8, 64)).astype(np.float32)
 def kern(x):
     return compressed_psum({"g": x[0]}, "data")["g"]
-fn = shard_map(kern, mesh=mesh, in_specs=P("data"), out_specs=P("data"))
+fn = jax.shard_map(kern, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+                   check_vma=False)
 out = np.asarray(fn(g)).reshape(8, 64)
 exact = g.mean(axis=0)
 rel = np.linalg.norm(out[0] - exact) / np.linalg.norm(exact)

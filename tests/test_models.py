@@ -171,3 +171,20 @@ def test_param_counts_match_public_sizes():
     for arch, (lo, hi) in expect.items():
         n = get_config(arch).total_params
         assert lo <= n <= hi, (arch, n)
+
+
+def test_make_embed_fn_matches_encode(rng):
+    """The gateway's bucketed embed_fn equals a plain encode of each
+    prompt, cut or zero-padded to the bucket, whatever batch it rides in."""
+    cfg = get_config("siso-embedder").reduced()
+    p = embedder.init_params(jax.random.PRNGKey(0), cfg)
+    fn = embedder.make_embed_fn(p, cfg, seq_len=8, batch=4)
+    toks = [rng.integers(1, cfg.vocab_size, size=n) for n in (3, 8, 12, 5, 1)]
+    out = fn(toks)
+    assert out.shape == (5, cfg.d_model) and out.dtype == np.float32
+    encode = jax.jit(lambda p, t: embedder.encode(p, cfg, t))
+    for t, o in zip(toks, out):
+        padded = np.zeros((1, 8), np.int32)
+        padded[0, :min(len(t), 8)] = t[:8]
+        ref = np.asarray(encode(p, jnp.asarray(padded))[0])
+        np.testing.assert_allclose(o, ref, atol=1e-6)

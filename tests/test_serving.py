@@ -252,3 +252,60 @@ def test_cache_admission_skips_engine(rng):
     sched.submit(Request(rid=0, tokens=np.asarray([1, 2, 3], np.int32),
                          max_new=4, vector=hot))
     assert sched.done and sched.done[0].served_by == "cache"
+
+
+# ---------------------------------------------------------------------------
+# serve entry point (launch/serve.py)
+# ---------------------------------------------------------------------------
+
+
+def test_reduced_is_a_switch():
+    """--reduced (the default) gives the toy width; --no-reduced the
+    published one."""
+    from repro.launch import serve
+    small = serve._model_config(serve.parse_args(["--arch", "minicpm3-4b"]))
+    full = serve._model_config(
+        serve.parse_args(["--arch", "minicpm3-4b", "--no-reduced"]))
+    assert small.d_model == 64
+    assert full.d_model == get_config("minicpm3-4b").d_model == 2560
+    assert full.n_layers == 62 and not full.remat
+
+
+def test_socket_transport_refused_on_tpu(monkeypatch):
+    """One process per replica cannot share a TPU chip: the socket mode
+    refuses and names the in-process transport."""
+    from repro.launch import serve
+    monkeypatch.setattr(serve, "tpu_attached", lambda: True)
+    with pytest.raises(SystemExit) as e:
+        serve.main(["--mode", "replica", "--transport", "socket"])
+    assert "--transport inproc" in str(e.value.code)
+
+
+def test_tpu_attached_honours_jax_platforms(monkeypatch):
+    from repro.launch import serve
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert serve.tpu_attached() is False
+
+
+@pytest.mark.parametrize("env_dir", [False, True])
+def test_compile_cache_location(tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins where set; otherwise the cache is the
+    fixed in-checkout .jax_cache. Run in a child: the setting is global."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    root = pathlib.Path(__file__).resolve().parents[1]
+    env = dict(os.environ, PYTHONPATH=str(root / "src"),
+               JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = str(root / ".jax_cache")
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = want = str(tmp_path)
+    code = ("import jax; from repro.launch.serve import "
+            "enable_compile_cache as f; print(f()); "
+            "print(jax.config.jax_compilation_cache_dir)")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == [want, want]
