@@ -148,11 +148,6 @@ def run(capacity: int, n_topics: int, per_topic: int, steps: int,
         out = serve(s, questions, answers, sched)
         if name == "tiered":
             out["tier_stats"] = s.cache.tier_stats()
-            plat = np.asarray(s.cache.promote_latencies, np.float64)
-            out["promotion_p99_ms"] = (float(np.percentile(plat, 99) * 1e3)
-                                       if len(plat) else 0.0)
-            out["promotion_p50_ms"] = (float(np.percentile(plat, 50) * 1e3)
-                                       if len(plat) else 0.0)
         results[name] = out
         print(f"  {name:12s} hit_ratio {out['hit_ratio']:.3f} "
               f"p99 {out['p99_ms']:.2f}ms")
@@ -171,7 +166,6 @@ def run(capacity: int, n_topics: int, per_topic: int, steps: int,
         # +0.5ms absolute guard: at smoke sizes both p99s are ~1ms and a
         # single GC pause would otherwise flap a pure-ratio bound
         "p99_within_2x": bool(t["p99_ms"] <= 2.0 * d["p99_ms"] + 0.5),
-        "promotion_p99_ms": t["promotion_p99_ms"],
     }
 
 
@@ -203,8 +197,7 @@ def main(argv=None) -> int:
     print(f"wrote {path}")
     print(f"  lift {payload['hit_ratio_lift_10x']:+.3f} at "
           f"{payload['pressure_x']:.0f}x pressure; p99 ratio "
-          f"{payload['p99_ratio']:.2f}; promotion p99 "
-          f"{payload['promotion_p99_ms']:.3f}ms")
+          f"{payload['p99_ratio']:.2f}")
 
     import shutil
     shutil.rmtree(workdir, ignore_errors=True)

@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.core.clustering import _pow2_pad
 from repro.core.store import CentroidStore
 from repro.distributed.cache_plane import (ShardedCacheConfig,
@@ -221,6 +222,9 @@ class LookupResult:
     region: np.ndarray     # (B,) int8: 0 centroid, 1 spill, -1 miss
     generation: int = -1   # serving-state generation (DESIGN.md §10);
                            # -1 for frontends without a device mirror
+    tiles: Optional[tuple] = None  # (tiles computed, tiles in the grid)
+                                   # of the f32 kernel's scan; None for
+                                   # the other backends
 
 
 class SemanticCache:
@@ -724,51 +728,15 @@ class SemanticCache:
                                 np.full(B, -1, np.int64),
                                 np.full(B, -1, np.int8),
                                 generation=self.generation)
+        tiles = None
         if self.backend == "hnsw":
             sims, idx = self._hnsw_lookup(queries)
             hit = sims >= theta_r
             answer, answer_id = self._host_gather(hit, idx, nc, B)
-        elif self.backend == "pallas_q8":
-            # int8 plane (DESIGN.md §15): fused dequant-cosine top-C on
-            # device, exact margin rescore host-driven; answers are host
-            # resident — the same vectorized gather the hnsw path uses
-            sims, idx = self._quant_lookup(queries, theta_r)
-            # f32-exact compare: the device reference compares f32 sims
-            # against f32(theta), so the host must too (a float64 theta
-            # can sit strictly between a sim and its f32 rounding)
-            hit = sims >= np.float32(theta_r)
-            answer, answer_id = self._host_gather(hit, idx, nc, B)
-        elif self.shard is not None:
-            # mesh plane: shard-local fused top-1 + cross-shard argmax
-            # (dense or pallas shard-local compute — DESIGN.md §11)
-            dev = self._device_state()
-            h, s, i, a, ai = dev.lookup(queries, theta_r)
-            hit, sims, idx, answer, answer_id = (
-                np.array(x) for x in jax.device_get((h, s, i, a, ai)))
-            answer_id = answer_id.astype(np.int64)
-        elif self.backend == "pallas":
-            from repro.kernels.cosine_topk import ops as ctk_ops
-            dev = self._device_state()
-            # early-accept only for real serving thresholds: probe lookups
-            # (T2HTable.build passes theta_r=-1.0) need exact top-1 sims,
-            # and with theta <= 0 every row clears the bar after tile 0.
-            s, i, h = ctk_ops.cosine_topk(
-                jnp.asarray(queries), dev.mat, k=1,
-                valid=dev.valid, theta=theta_r,
-                early_exit=bool(theta_r > 0), return_hit=True)
-            a, ai = _gather_hits(dev.ans, dev.aid, i[:, 0], h)
-            sims, idx, hit, answer, answer_id = (
-                np.array(x) for x in jax.device_get((s[:, 0], i[:, 0], h,
-                                                     a, ai)))
-            answer_id = answer_id.astype(np.int64)
         else:
-            dev = self._device_state()
-            h, s, i, a, ai = _fused_top1(jnp.asarray(queries), dev.mat,
-                                         dev.ans, dev.valid, dev.aid,
-                                         theta_r)
-            hit, sims, idx, answer, answer_id = (
-                np.array(x) for x in jax.device_get((h, s, i, a, ai)))
-            answer_id = answer_id.astype(np.int64)
+            with trace.span("lookup.scan", n=B):
+                hit, sims, idx, answer, answer_id, tiles = \
+                    self._device_lookup(queries, theta_r, nc, B)
         idx = np.asarray(idx, np.int64)
         region = np.where(~hit, -1, np.where(idx < nc, 0, 1)).astype(np.int8)
         if update_counts:
@@ -787,7 +755,52 @@ class SemanticCache:
             self.misses += int(B - hit.sum())
         entry = np.where(hit, idx, -1).astype(np.int64)
         return LookupResult(hit, sims.astype(np.float32), answer, answer_id,
-                            entry, region, generation=self.generation)
+                            entry, region, generation=self.generation,
+                            tiles=tiles)
+
+    def _device_lookup(self, queries: np.ndarray, theta_r: float, nc: int,
+                       B: int) -> tuple:
+        """The device backends' lookup: (hit, sims, idx, answer, answer_id,
+        tiles), each a host array (tiles: see LookupResult)."""
+        if self.backend == "pallas_q8":
+            # int8 plane (DESIGN.md §15): fused dequant-cosine top-C on
+            # device, exact margin rescore host-driven; answers are host
+            # resident — the same vectorized gather the hnsw path uses
+            sims, idx = self._quant_lookup(queries, theta_r)
+            # f32-exact compare: the device reference compares f32 sims
+            # against f32(theta), so the host must too (a float64 theta
+            # can sit strictly between a sim and its f32 rounding)
+            hit = sims >= np.float32(theta_r)
+            answer, answer_id = self._host_gather(hit, idx, nc, B)
+            return hit, sims, idx, answer, answer_id, None
+        tiles = None
+        if self.shard is not None:
+            # mesh plane: shard-local fused top-1 + cross-shard argmax
+            # (dense or pallas shard-local compute — DESIGN.md §11)
+            out = self._device_state().lookup(queries, theta_r)
+        elif self.backend == "pallas":
+            from repro.kernels.cosine_topk import ops as ctk_ops
+            dev = self._device_state()
+            # early-accept only for real serving thresholds: probe lookups
+            # (T2HTable.build passes theta_r=-1.0) need exact top-1 sims,
+            # and with theta <= 0 every row clears the bar after tile 0.
+            s, i, h, t = ctk_ops.cosine_topk(
+                jnp.asarray(queries), dev.mat, k=1,
+                valid=dev.valid, theta=theta_r,
+                early_exit=bool(theta_r > 0), return_hit=True,
+                return_tiles=True)
+            a, ai = _gather_hits(dev.ans, dev.aid, i[:, 0], h)
+            out, tiles = (h, s[:, 0], i[:, 0], a, ai), t
+        else:
+            dev = self._device_state()
+            out = _fused_top1(jnp.asarray(queries), dev.mat, dev.ans,
+                              dev.valid, dev.aid, theta_r)
+        with trace.span("lookup.wait"):
+            out, tiles = jax.device_get((out, tiles))
+        hit, sims, idx, answer, answer_id = (np.array(x) for x in out)
+        if tiles is not None:
+            tiles = (int(tiles[0]), int(tiles[1]))
+        return hit, sims, idx, answer, answer_id.astype(np.int64), tiles
 
     def _quant_lookup(self, queries: np.ndarray, theta_r: float
                       ) -> tuple[np.ndarray, np.ndarray]:
@@ -814,7 +827,9 @@ class SemanticCache:
             s, i = ctk_ops.cosine_topk_q8(
                 jnp.asarray(queries), dev.codes, dev.scales, k=C,
                 valid=dev.valid, theta=theta_r, early_exit=False)
-            cand_s, cand_r = (np.array(x) for x in jax.device_get((s, i)))
+            with trace.span("lookup.wait"):
+                cand_s, cand_r = (np.array(x)
+                                  for x in jax.device_get((s, i)))
             kth = cand_s[:, -1:]
         return self._rescore_exact(queries, cand_s, cand_r, kth,
                                    dev.err_max)
@@ -834,46 +849,48 @@ class SemanticCache:
         window isn't covered (rare: near-ties deeper than C) fall back to
         the dense f32 reference, which is exact by construction.
         """
-        B = len(queries)
-        qn = np.linalg.norm(queries.astype(np.float64), axis=1)
-        eps = err_max * qn + QUANT_SLACK                     # (B,)
-        finite = np.isfinite(cand_s)
-        m = np.max(np.where(finite, cand_s, -np.inf), axis=1,
-                   initial=-np.inf)
-        # covered: per (query, shard-window) either the window was
-        # exhausted (C-th is -inf) or its C-th quant sim is strictly
-        # below the safe bar — no candidate can be missing
-        bar = (m - 2.0 * eps)[:, None]
-        covered = ((~np.isfinite(kth)) | (kth < bar)).all(axis=1)
-        if not covered.all():
-            self.quant_fallbacks += 1
-            return self._dense_reference_lookup(queries)
-        rows = np.unique(cand_r[finite].astype(np.int64))    # sorted asc
-        if not len(rows):                                    # B == 0
-            return (np.full(B, -1.0, np.float32),
-                    np.zeros(B, np.int64))
-        self.quant_rescored += int(len(rows))
-        nc = len(self.centroids)
-        n = nc + len(self.spill)
-        # Scatter the fetched rows at their original positions inside a
-        # zero matrix of the REFERENCE shape (_pow2_pad(n) rows — the
-        # dense mirror's padding rule). XLA CPU's contraction blocking
-        # (and hence the f32 reduction order) depends on the operand
-        # shape: a compacted (U, D) submatrix can differ from the full
-        # matmul in the last ulp on some hosts. Same shape + same row
-        # position == the reference computation with non-candidate rows
-        # zeroed, bit for bit.
-        vecs = np.zeros((_pow2_pad(n), self.dim), np.float32)
-        c_rows = rows < nc
-        if c_rows.any():
-            vecs[rows[c_rows]] = self.centroids.vectors[rows[c_rows]]
-        if (~c_rows).any():
-            vecs[rows[~c_rows]] = self.spill.vectors[rows[~c_rows] - nc]
-        sims = np.asarray(_rescore_mm(jnp.asarray(queries),
-                                      jnp.asarray(vecs)))[:, rows]  # (B, U)
-        pos = np.argmax(sims, axis=1)        # first max -> lowest row
-        best = sims[np.arange(B), pos]
-        return best.astype(np.float32), rows[pos]
+        with trace.span("lookup.rescore", n=len(queries)):
+            B = len(queries)
+            qn = np.linalg.norm(queries.astype(np.float64), axis=1)
+            eps = err_max * qn + QUANT_SLACK                     # (B,)
+            finite = np.isfinite(cand_s)
+            m = np.max(np.where(finite, cand_s, -np.inf), axis=1,
+                       initial=-np.inf)
+            # covered: per (query, shard-window) either the window was
+            # exhausted (C-th is -inf) or its C-th quant sim is strictly
+            # below the safe bar — no candidate can be missing
+            bar = (m - 2.0 * eps)[:, None]
+            covered = ((~np.isfinite(kth)) | (kth < bar)).all(axis=1)
+            if not covered.all():
+                self.quant_fallbacks += 1
+                return self._dense_reference_lookup(queries)
+            rows = np.unique(cand_r[finite].astype(np.int64))    # sorted asc
+            if not len(rows):                                    # B == 0
+                return (np.full(B, -1.0, np.float32),
+                        np.zeros(B, np.int64))
+            self.quant_rescored += int(len(rows))
+            nc = len(self.centroids)
+            n = nc + len(self.spill)
+            # Scatter the fetched rows at their original positions inside a
+            # zero matrix of the REFERENCE shape (_pow2_pad(n) rows — the
+            # dense mirror's padding rule). XLA CPU's contraction blocking
+            # (and hence the f32 reduction order) depends on the operand
+            # shape: a compacted (U, D) submatrix can differ from the full
+            # matmul in the last ulp on some hosts. Same shape + same row
+            # position == the reference computation with non-candidate rows
+            # zeroed, bit for bit.
+            vecs = np.zeros((_pow2_pad(n), self.dim), np.float32)
+            c_rows = rows < nc
+            if c_rows.any():
+                vecs[rows[c_rows]] = self.centroids.vectors[rows[c_rows]]
+            if (~c_rows).any():
+                vecs[rows[~c_rows]] = self.spill.vectors[rows[~c_rows] - nc]
+            sims = _rescore_mm(jnp.asarray(queries), jnp.asarray(vecs))
+            with trace.span("lookup.rescore.wait"):
+                sims = np.asarray(sims)[:, rows]                 # (B, U)
+            pos = np.argmax(sims, axis=1)        # first max -> lowest row
+            best = sims[np.arange(B), pos]
+            return best.astype(np.float32), rows[pos]
 
     def _dense_reference_lookup(self, queries: np.ndarray
                                 ) -> tuple[np.ndarray, np.ndarray]:

@@ -35,7 +35,6 @@ work to the device path: it degrades bit-identical to today's
 """
 from __future__ import annotations
 
-import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Optional
@@ -448,7 +447,6 @@ class TieredCache:
         self.drops = 0           # overflow evictions out of the hierarchy
         self._promo: deque = deque()       # (region, entry_id) FIFO
         self._promo_set: set = set()
-        self.promote_latencies: deque = deque(maxlen=4096)
         self._last_sweep = 0
         # multi-tenant fair-share eviction (DESIGN.md §14): mirrors the
         # device tier's knobs — when SISO wires both, lower-tier capacity
@@ -824,7 +822,6 @@ class TieredCache:
             self._promo_set.discard((region, eid))
             if not self.device.spill_lru or self.device.spill_capacity == 0:
                 continue        # nowhere to promote into; entry stays put
-            t0 = time.perf_counter()
             tier = self.host if region == REGION_HOST else self.disk
             if tier is None:
                 continue
@@ -842,7 +839,6 @@ class TieredCache:
             self.device.insert_spill(vec, ans, int(aid),
                                      cluster_size=float(cs))
             self.promotions += 1
-            self.promote_latencies.append(time.perf_counter() - t0)
             n += 1
         self._maybe_sweep()
         return n
@@ -952,7 +948,6 @@ class TieredCache:
         promo = np.asarray(state["promo"], np.int64).reshape(-1, 2)
         self._promo = deque((int(r), int(i)) for r, i in promo)
         self._promo_set = set(self._promo)
-        self.promote_latencies = deque(maxlen=4096)
         self._last_sweep = int(state["last_sweep"])
         if self.host is not None:
             if "host" not in state:

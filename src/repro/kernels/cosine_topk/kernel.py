@@ -52,10 +52,12 @@ def _merge_topk(run_vals, run_idx, sims, idx, k: int):
 
 
 def cosine_topk_kernel(theta_ref, q_ref, c_ref, valid_ref, vals_ref, idx_ref,
-                       hit_ref, *, k: int, block_n: int, early_exit: bool):
+                       hit_ref, tiles_ref, *, k: int, block_n: int,
+                       early_exit: bool):
     """Grid: (num_centroid_tiles,). q block (B, D) constant; c tile
-    (block_n, D) streams; vals/idx/hit (B, k)/(B, k)/(B, 1) revisited
-    accumulators.
+    (block_n, D) streams; vals/idx/hit/tiles (B, k)/(B, k)/(B, 1)/(1, 1)
+    revisited accumulators. ``tiles`` counts the tiles whose compute ran
+    (the grid less the tiles early exit skipped).
 
     The hit mask is the theta_R early-accept (DESIGN.md §4): per query,
     ``best similarity >= theta`` the moment the tile that produced the best
@@ -70,8 +72,10 @@ def cosine_topk_kernel(theta_ref, q_ref, c_ref, valid_ref, vals_ref, idx_ref,
         vals_ref[...] = jnp.full(vals_ref.shape, NEG, jnp.float32)
         idx_ref[...] = jnp.full(idx_ref.shape, -1, jnp.int32)
         hit_ref[...] = jnp.zeros(hit_ref.shape, jnp.int32)
+        tiles_ref[...] = jnp.zeros(tiles_ref.shape, jnp.int32)
 
     def _compute():
+        tiles_ref[...] += 1
         q = q_ref[...]
         c = c_ref[...]
         sims = jax.lax.dot_general(

@@ -50,27 +50,34 @@ def quantize_rows(rows: np.ndarray, width: int | None = None
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret",
-                                             "early_exit", "return_hit"))
+                                             "early_exit", "return_hit",
+                                             "return_tiles"))
+@jax.named_scope("cosine_topk")
 def cosine_topk(queries: jax.Array, centroids: jax.Array, k: int = 1,
                 valid: jax.Array | None = None,
                 theta: float | jax.Array = 2.0,
                 block_n: int = 512, interpret: bool | None = None,
-                early_exit: bool = False, return_hit: bool = False):
+                early_exit: bool = False, return_hit: bool = False,
+                return_tiles: bool = False):
     """queries (B, D) x centroids (N, D) -> (sims (B, k) f32, idx (B, k) i32).
 
     valid: (N,) bool/int — rows to consider (default all). theta=2.0 (never
     reached) disables early exit even when compiled with early_exit=True.
     With ``return_hit`` a third output (B,) bool is appended: the kernel's
     theta_R early-accept mask (best sim >= theta), so the serving cache gets
-    hit decisions straight off the device with no host re-compare.
+    hit decisions straight off the device with no host re-compare. With
+    ``return_tiles`` a last output (2,) int32 is appended: the grid tiles
+    whose compute ran (early exit skips the rest) and the tiles in the grid.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     B, D = queries.shape
     N, Dc = centroids.shape
     if B == 0:
-        empty = (jnp.zeros((0, k), jnp.float32), jnp.zeros((0, k), jnp.int32))
-        return (*empty, jnp.zeros((0,), bool)) if return_hit else empty
+        out = (jnp.zeros((0, k), jnp.float32), jnp.zeros((0, k), jnp.int32))
+        if return_hit:
+            out += (jnp.zeros((0,), bool),)
+        return out + (jnp.zeros((2,), jnp.int32),) if return_tiles else out
     # --- padding: D to lane width, N to tile, B to sublane count ---
     Dp = _ceil_to(max(D, Dc, 1), 128)
     Bp = _ceil_to(max(B, 1), 8)
@@ -104,7 +111,7 @@ def cosine_topk(queries: jax.Array, centroids: jax.Array, k: int = 1,
     grid = (Np // block_n,)
     kern = functools.partial(cosine_topk_kernel, k=k, block_n=block_n,
                              early_exit=early_exit)
-    vals, idx, hit = pl.pallas_call(
+    vals, idx, hit, tiles = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -118,20 +125,25 @@ def cosine_topk(queries: jax.Array, centroids: jax.Array, k: int = 1,
                 pl.BlockSpec((Bp, k), lambda t, *_: (0, 0)),
                 pl.BlockSpec((Bp, k), lambda t, *_: (0, 0)),
                 pl.BlockSpec((Bp, 1), lambda t, *_: (0, 0)),
+                pl.BlockSpec((1, 1), lambda t, *_: (0, 0)),         # tiles run
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((Bp, k), jnp.float32),
             jax.ShapeDtypeStruct((Bp, k), jnp.int32),
             jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
+            jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
         interpret=interpret,
     )(theta_arr, q, c, v)
     vals, idx = vals[:B], idx[:B]
     idx = jnp.where(jnp.isfinite(vals), idx, -1)
+    out = (vals, idx)
     if return_hit:
-        return vals, idx, hit[:B, 0].astype(bool)
-    return vals, idx
+        out += (hit[:B, 0].astype(bool),)
+    if return_tiles:
+        out += (jnp.stack([tiles[0, 0], jnp.int32(grid[0])]),)
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret",

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.configs.base import ModelConfig
 from repro.configs.siso_embedder import EMBED_FACTOR_DIM
 from repro.models import layers as L
@@ -39,6 +40,7 @@ def init_params(key, cfg: ModelConfig) -> Params:
     }
 
 
+@jax.named_scope("embed.encode")
 def encode(p: Params, cfg: ModelConfig, tokens: jax.Array,
            mask: jax.Array | None = None) -> jax.Array:
     """tokens: (B, L) int32; mask: (B, L) bool (True = real token).
@@ -75,14 +77,16 @@ def make_embed_fn(p: Params, cfg: ModelConfig, seq_len: int, batch: int
     def embed(token_lists: Sequence[np.ndarray]) -> np.ndarray:
         n = len(token_lists)
         out = np.zeros((n, cfg.d_model), np.float32)
-        for s in range(0, n, batch):
-            chunk = token_lists[s:s + batch]
-            toks = np.zeros((batch, seq_len), np.int32)
-            for i, t in enumerate(chunk):
-                t = np.asarray(t, np.int32)[:seq_len]
-                toks[i, :len(t)] = t
-            out[s:s + len(chunk)] = np.asarray(
-                enc(p, tokens=jnp.asarray(toks)))[:len(chunk)]
+        with trace.span("embed", n=n):
+            for s in range(0, n, batch):
+                chunk = token_lists[s:s + batch]
+                toks = np.zeros((batch, seq_len), np.int32)
+                for i, t in enumerate(chunk):
+                    t = np.asarray(t, np.int32)[:seq_len]
+                    toks[i, :len(t)] = t
+                e = enc(p, tokens=jnp.asarray(toks))
+                with trace.span("embed.wait"):
+                    out[s:s + len(chunk)] = np.asarray(e)[:len(chunk)]
         return out
 
     return embed

@@ -432,6 +432,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("lm.prefill")
 def prefill(p: Params, cfg: ModelConfig, batch: dict, cache: Params
             ) -> tuple[jax.Array, Params]:
     """Process the full prompt; fill the cache; return last-position logits.
@@ -569,6 +570,7 @@ def prefill(p: Params, cfg: ModelConfig, batch: dict, cache: Params
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("lm.decode_step")
 def decode_step(p: Params, cfg: ModelConfig, tokens: jax.Array, cache: Params,
                 pos: jax.Array, kv_len: Optional[jax.Array] = None
                 ) -> tuple[jax.Array, Params]:

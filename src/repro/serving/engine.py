@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.configs.base import ModelConfig
 
 # Hardware constants (TPU v5e class; see EXPERIMENTS.md §Roofline)
@@ -165,21 +166,24 @@ class ModelEngine:
     def prefill_into(self, slot: int, tokens: np.ndarray) -> int:
         """Prefill a (Lp,) prompt into `slot`; returns the first token."""
         lp = len(tokens)
-        batch = {"tokens": jnp.asarray(tokens, jnp.int32)[None]}
-        cache1 = self.lm.init_cache(self.cfg, 1, self.max_len)
-        logits, cache1 = self._jit_prefill(self.params, batch=batch,
-                                           cache=cache1)
+        with trace.span("engine.prefill", n=lp):
+            batch = {"tokens": jnp.asarray(tokens, jnp.int32)[None]}
+            cache1 = self.lm.init_cache(self.cfg, 1, self.max_len)
+            logits, cache1 = self._jit_prefill(self.params, batch=batch,
+                                               cache=cache1)
 
-        def place(full, one):
-            idx = [0] * full.ndim
-            idx[1] = slot
-            return jax.lax.dynamic_update_slice(full, one.astype(full.dtype),
-                                                tuple(idx))
+            def place(full, one):
+                idx = [0] * full.ndim
+                idx[1] = slot
+                return jax.lax.dynamic_update_slice(
+                    full, one.astype(full.dtype), tuple(idx))
 
-        self.cache = jax.tree.map(place, self.cache, cache1)
-        self.pos[slot] = lp
-        self.active[slot] = True
-        return int(jnp.argmax(logits[0]))
+            self.cache = jax.tree.map(place, self.cache, cache1)
+            self.pos[slot] = lp
+            self.active[slot] = True
+            first = jnp.argmax(logits[0])
+            with trace.span("engine.prefill.wait"):
+                return int(first)
 
     def decode_active(self, tokens: np.ndarray) -> np.ndarray:
         """One decode step for every slot (inactive slots decode garbage
@@ -191,11 +195,14 @@ class ModelEngine:
         (pos below, tokens by the scheduler's retire loop) while the
         async computation may still be reading them — a data race that
         surfaced as run-to-run nondeterministic decode output."""
-        logits, self.cache = self._jit_decode(
-            self.params, jnp.array(tokens, jnp.int32)[:, None],
-            self.cache, jnp.array(self.pos))
-        self.pos[self.active] += 1
-        return np.asarray(jnp.argmax(logits, axis=-1))
+        with trace.span("engine.decode"):
+            logits, self.cache = self._jit_decode(
+                self.params, jnp.array(tokens, jnp.int32)[:, None],
+                self.cache, jnp.array(self.pos))
+            self.pos[self.active] += 1
+            nxt = jnp.argmax(logits, axis=-1)
+            with trace.span("engine.decode.wait"):
+                return np.asarray(nxt)
 
     def release(self, slot: int) -> None:
         self.active[slot] = False

@@ -38,6 +38,7 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from repro import trace
 from repro.checkpoint import CheckpointManager
 from repro.serving.engine import ModelEngine
 from repro.serving.scheduler import ContinuousBatchScheduler, Request
@@ -72,8 +73,6 @@ class GatewayStats:
     submitted: int = 0
     refreshes: int = 0
     lookup_s: deque = field(default_factory=lambda: deque(maxlen=STATS_WINDOW))
-    batch_sizes: deque = field(
-        default_factory=lambda: deque(maxlen=STATS_WINDOW))
     # (now, theta_R) sampled once per submitted batch — the live trace of
     # the dynamic-threshold operating point under this gateway's load
     theta_trace: deque = field(
@@ -182,6 +181,11 @@ class ServingGateway:
         misses enter the engine queue. Returns the (B,) hit mask."""
         if not len(batch):
             return np.zeros(0, bool)
+        with trace.span("gateway.submit", key=batch[0].rid, n=len(batch)):
+            return self._submit(batch, now)
+
+    def _submit(self, batch: Sequence[GatewayRequest],
+                now: Optional[float]) -> np.ndarray:
         now = self.clock() if now is None else now
         missing = [r.embed_tokens is None for r in batch]
         if any(missing) and not all(missing):
@@ -209,19 +213,19 @@ class ServingGateway:
             # tenant-free traffic exercises the exact pre-tenancy path
             tenant_ids = np.asarray([-1 if r.tenant is None else r.tenant
                                      for r in batch])
-        t0 = time.perf_counter()
-        if hasattr(self.frontend, "handle_batch"):
-            if tenant_ids is not None:
-                res = self.frontend.handle_batch(vectors, now=now,
-                                                 user_ids=user_ids,
-                                                 tenant_ids=tenant_ids)
+        with trace.span("lookup", clocked=True, n=len(batch)) as sp:
+            if hasattr(self.frontend, "handle_batch"):
+                if tenant_ids is not None:
+                    res = self.frontend.handle_batch(vectors, now=now,
+                                                     user_ids=user_ids,
+                                                     tenant_ids=tenant_ids)
+                else:
+                    res = self.frontend.handle_batch(vectors, now=now,
+                                                     user_ids=user_ids)
             else:
-                res = self.frontend.handle_batch(vectors, now=now,
-                                                 user_ids=user_ids)
-        else:
-            res = self.frontend.lookup(vectors, now=now, user_ids=user_ids)
-        self.stats.lookup_s.append(time.perf_counter() - t0)
-        self.stats.batch_sizes.append(len(batch))
+                res = self.frontend.lookup(vectors, now=now,
+                                           user_ids=user_ids)
+        self.stats.lookup_s.append(sp.t1 - sp.t0)
         self.stats.submitted += len(batch)
         self.last_result = res
         theta = getattr(self.frontend, "theta_r", None)
@@ -337,7 +341,6 @@ class ServingGateway:
             "submitted": np.asarray(self.stats.submitted),
             "refreshes": np.asarray(self.stats.refreshes),
             "lookup_s": np.asarray(self.stats.lookup_s, np.float64),
-            "batch_sizes": np.asarray(self.stats.batch_sizes, np.int64),
             "theta_trace": trace,
             "served_cache": np.asarray(self._served["cache"]),
             "served_engine": np.asarray(self._served["engine"]),
@@ -365,8 +368,6 @@ class ServingGateway:
         st.refreshes = int(state["refreshes"])
         st.lookup_s = deque(np.asarray(state["lookup_s"]).tolist(),
                             maxlen=STATS_WINDOW)
-        st.batch_sizes = deque(
-            np.asarray(state["batch_sizes"]).tolist(), maxlen=STATS_WINDOW)
         st.theta_trace = deque(
             (tuple(p) for p in np.asarray(
                 state["theta_trace"]).reshape(-1, 2)),

@@ -14,6 +14,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro import trace
 from repro.serving.engine import ModelEngine
 
 
@@ -34,6 +35,7 @@ class Request:
     out: list = field(default_factory=list)
     slot: int = -1
     t_submit: float = 0.0
+    t_admit: float = 0.0         # popped from the queue into a slot
     t_first: float = 0.0
     t_done: float = 0.0
     served_by: str = "engine"    # engine | cache
@@ -96,13 +98,19 @@ class ContinuousBatchScheduler:
         """One scheduler tick: admit -> prefill -> batched decode -> retire.
         Returns number of active slots after the tick."""
         self._tick += 1
+        with trace.span("sched.step", key=self._tick):
+            return self._step()
+
+    def _step(self) -> int:
         eng = self.engine
         # admit
         for slot in eng.free_slots():
             if not self.queue:
                 break
             req = self.queue.popleft()
-            first = eng.prefill_into(slot, req.tokens)
+            req.t_admit = self.clock()
+            with trace.keyed(req.rid):
+                first = eng.prefill_into(slot, req.tokens)
             req.slot = slot
             req.t_first = self.clock()
             req.out.append(first)
@@ -120,16 +128,19 @@ class ContinuousBatchScheduler:
             full = eng.pos[slot] >= eng.max_len - 1
             if tok == req.eos_id or len(req.out) >= req.max_new or full:
                 retired.append(slot)
-        for slot in retired:
-            req = self.active.pop(slot)
-            req.t_done = self.clock()
-            eng.release(slot)
-            self.done.append(req)
-            self._record(req)
-            # close the control loop: this completion's realized sojourn
-            # and measured engine service time feed the dynamic threshold
-            # (±10% wait feedback + service-time EMA calibration)
-            self._observe(req)
+        if retired:
+            with trace.span("sched.retire", n=len(retired)):
+                for slot in retired:
+                    req = self.active.pop(slot)
+                    req.t_done = self.clock()
+                    eng.release(slot)
+                    self.done.append(req)
+                    self._record(req)
+                    # close the control loop: this completion's realized
+                    # sojourn and measured engine service time feed the
+                    # dynamic threshold (±10% wait feedback + service-time
+                    # EMA calibration)
+                    self._observe(req)
         return len(self.active)
 
     def drain(self, max_ticks: int = 10_000) -> list[Request]:

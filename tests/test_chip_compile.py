@@ -62,6 +62,20 @@ def test_cosine_topk_compiles_for_v5e(one_chip, k):
         interpret=False))
 
 
+def test_cosine_topk_tile_count_compiles_for_v5e(one_chip):
+    """The served lookup's variant: top-1, early exit, the hit mask and the
+    count of tiles computed."""
+    from repro.kernels.cosine_topk import ops
+    f32 = jnp.float32
+    compiled = _compiled(ops.cosine_topk.lower(
+        _spec((BATCH, DIM), f32, one_chip), _spec((N_ROWS, DIM), f32, one_chip),
+        k=1, valid=_spec((N_ROWS,), jnp.bool_, one_chip),
+        theta=_spec((), f32, one_chip), early_exit=True, return_hit=True,
+        return_tiles=True, interpret=False))
+    tiles = compiled.out_info[-1]
+    assert tiles.shape == (2,) and tiles.dtype == jnp.int32
+
+
 @pytest.mark.parametrize("k", [1, RESCORE_K])
 def test_cosine_topk_q8_compiles_for_v5e(one_chip, k):
     from repro.kernels.cosine_topk import ops

@@ -433,3 +433,91 @@ def test_gateway_tenant_report_and_counter_persistence(rng, tiny_engine):
                          clock=clock, slo_latency=10.0)
     gw3.load_state(st)
     assert gw3._tenant_counts == {}
+
+
+# ---------------------------------------------------------------------------
+# spans and counters on the served path
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("backend", ["dense", "pallas"])
+def test_gateway_records_its_spans_and_counters(rng, tiny_engine, backend):
+    """With recording on, one submit and its drain leave the served path's
+    spans, keyed by the batch's first rid, the scheduler tick and the
+    prefill's rid; the gateway's lookup timer reads the lookup span's own
+    clock pair; each engine request is stamped submitted <= admitted <=
+    first token; the f32 kernel reports its tiles, the dense path none."""
+    from repro import trace
+    from repro.serving.gateway import GatewayRequest, ServingGateway
+    engine, cfg = tiny_engine
+    d = 16
+    siso = SISO(SISOConfig(dim=d, answer_dim=d, capacity=64,
+                           dynamic_threshold=False, theta_r=0.9,
+                           backend=backend))
+    hist = _unit(rng, 40, d)
+    siso.bootstrap(hist, hist, answer_ids=np.arange(40))
+    gw = ServingGateway(siso, engine, embed_fn=lambda vs: np.stack(vs))
+    vecs = np.concatenate([siso.cache.centroids.vectors[:1], _unit(rng, 3)])
+    reqs = [GatewayRequest(rid=10 + i, model_tokens=np.asarray(
+        [1, 2, 3 + i], np.int32), embed_tokens=vecs[i], max_new=3,
+        answer_vec=vecs[i]) for i in range(4)]
+    trace.clear()
+    try:
+        with trace.recording():
+            hit = gw.submit(reqs)
+            gw.drain()
+        rec = trace.spans()
+    finally:
+        trace.clear()
+    assert hit.tolist() == [True, False, False, False]
+    names = {s.name for s in rec}
+    assert {"gateway.submit", "lookup", "lookup.scan", "lookup.wait",
+            "sched.step", "engine.prefill", "engine.prefill.wait",
+            "engine.decode", "engine.decode.wait", "sched.retire"} <= names
+    assert not any(s.name.startswith("bench.") for s in rec)
+    by = {}
+    for s in rec:
+        by.setdefault(s.name, []).append(s)
+    (sub,) = by["gateway.submit"]
+    (look,) = by["lookup"]
+    assert sub.key == look.key == by["lookup.wait"][0].key == 10
+    assert look.parent == sub.seq
+    assert gw.stats.lookup_s[-1] == look.t1 - look.t0
+    assert sorted(s.key for s in by["engine.prefill"]) == [11, 12, 13]
+    steps = {s.seq: s.key for s in by["sched.step"]}
+    assert all(steps[s.parent] == s.key for s in by["engine.decode"])
+    for r in gw.done:
+        if r.served_by == "engine":
+            assert r.t_submit <= r.t_admit <= r.t_first
+        else:
+            assert r.t_admit == 0.0
+    tiles = gw.last_result.tiles
+    if backend == "pallas":
+        assert tiles is not None and 1 <= tiles[0] <= tiles[1]
+    else:
+        assert tiles is None
+
+
+def test_gateway_snapshot_that_carries_batch_sizes_loads(rng, tiny_engine,
+                                                         tmp_path):
+    """Gateway snapshots written while GatewayStats kept batch_sizes hold
+    that entry; they load, and it is ignored."""
+    from repro.checkpoint import CheckpointManager
+    from repro.serving.gateway import GatewayRequest, ServingGateway
+    engine, cfg = tiny_engine
+    gw, siso = _make_gateway(rng, engine, cfg)
+    hot = siso.cache.centroids.vectors[:2].copy()
+    gw.submit([GatewayRequest(rid=i, model_tokens=np.asarray([1, 2], np.int32),
+                              embed_tokens=hot[i], max_new=2)
+               for i in range(2)])
+    gw.drain()
+    st = gw.state_dict()
+    assert "batch_sizes" not in st
+    st["batch_sizes"] = np.asarray([2], np.int64)
+    ckpt = CheckpointManager(str(tmp_path))
+    ckpt.save(1, {"gateway": st})
+    gw2 = ServingGateway(siso, engine, embed_fn=lambda vs: np.stack(vs))
+    gw2.load_state(ckpt.restore(1)["gateway"])
+    assert gw2.stats.submitted == 2
+    assert list(gw2.stats.lookup_s) == list(gw.stats.lookup_s)
+    assert not hasattr(gw2.stats, "batch_sizes")

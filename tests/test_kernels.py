@@ -70,6 +70,38 @@ def test_cosine_topk_all_invalid():
     assert (np.asarray(i) == -1).all()
 
 
+@pytest.mark.parametrize("rows", [[3, 5], [3, 700], [1500, 2], [1999]])
+def test_cosine_topk_counts_the_tiles_before_every_query_cleared(rows):
+    """With early exit the kernel computes tiles up to the one in which the
+    last query's best first clears theta, and counts them; the top-1 is
+    the one the kernel finds without the count."""
+    c = _unit(2000, 32)
+    q = c[rows]                       # each query's only match >= 0.99
+    block = 128
+    cleared = max(r // block for r in rows)
+    v, i, h, tiles = cosine_topk(jnp.asarray(q), jnp.asarray(c), k=1,
+                                 theta=0.99, block_n=block, early_exit=True,
+                                 return_hit=True, return_tiles=True)
+    assert np.asarray(tiles).tolist() == [cleared + 1, 16]
+    v0, i0, h0 = cosine_topk(jnp.asarray(q), jnp.asarray(c), k=1,
+                             theta=0.99, block_n=block, early_exit=True,
+                             return_hit=True)
+    assert np.asarray(i)[:, 0].tolist() == rows == np.asarray(i0)[:, 0]\
+        .tolist()
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(v0))
+    assert np.asarray(h).all() and np.asarray(h0).all()
+
+
+def test_cosine_topk_counts_the_whole_grid_without_early_exit():
+    q, c = _unit(3, 32), _unit(1000, 32)
+    v, i, tiles = cosine_topk(jnp.asarray(q), jnp.asarray(c), k=2,
+                              theta=-1.0, block_n=128, return_tiles=True)
+    assert np.asarray(tiles).tolist() == [8, 8]
+    rv, ri = cosine_topk_ref(jnp.asarray(q), jnp.asarray(c), k=2)
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
+    np.testing.assert_allclose(np.asarray(v), np.asarray(rv), atol=1e-5)
+
+
 # ---------------------------------------------------------------------------
 # flash_attention
 # ---------------------------------------------------------------------------

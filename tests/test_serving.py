@@ -187,6 +187,28 @@ def test_scheduler_serves_all_requests(rng):
         assert 1 <= len(r.out) <= 4
 
 
+def test_scheduler_stamps_admission(rng):
+    """t_admit is stamped when a queued request is popped into a slot,
+    before its prefill: a request that waits for a free slot is admitted
+    later than it was queued."""
+    import jax
+    from repro.models import lm
+    from repro.serving.engine import ModelEngine
+    from repro.serving.scheduler import ContinuousBatchScheduler, Request
+    cfg = get_config("qwen3-14b").reduced().replace(remat=False)
+    params = lm.init_params(jax.random.PRNGKey(0), cfg)
+    sched = ContinuousBatchScheduler(ModelEngine(params, cfg, n_slots=2,
+                                                 max_len=48))
+    for i in range(3):      # the scheduler's clock is its tick
+        toks = rng.integers(0, cfg.vocab_size, size=6).astype(np.int32)
+        sched.enqueue(Request(rid=i, tokens=toks, max_new=3))
+    done = {r.rid: r for r in sched.drain()}
+    for r in done.values():
+        assert r.t_submit <= r.t_admit <= r.t_first
+    assert done[0].t_admit == done[1].t_admit == 1.0
+    assert done[2].t_admit > done[0].t_admit    # waited for a slot
+
+
 def test_scheduler_continuous_batching_matches_sequential(rng):
     """Staggered continuous batching must produce the same tokens as
     serving each request alone (per-slot positions are independent).

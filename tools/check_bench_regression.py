@@ -80,9 +80,6 @@ METRICS = [
     ("BENCH_tiered.json", "lift_positive",
      "true", None, None,
      "3-tier hit ratio strictly above device-only at equal device memory"),
-    ("BENCH_tiered.json", "promotion_p99_ms",
-     "lower", "factor", 5.0,
-     "warm/cold -> device promotion apply p99 (generous: runner variance)"),
     ("BENCH_tiered.json", "p99_within_2x",
      "true", None, None,
      "3-tier lookup p99 within 2x of the single-tier lookup p99"),
