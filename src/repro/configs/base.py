@@ -61,7 +61,6 @@ class ModelConfig:
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
-    mla_absorb: bool = False  # absorbed decode matmuls (beyond-paper perf)
 
     # --- MLP / MoE ---
     act: str = "silu"

@@ -3,6 +3,8 @@
 MLA: q_lora_rank=768, kv_lora_rank=256, qk_nope=64, qk_rope=32, v_head=64.
 The assignment's "GQA kv=40" reflects MLA's effective per-head keys after
 up-projection (40 heads attend over a shared 256-dim latent cache).
+Prefill materialises those keys; decode attends in latent space
+(``layers.mla_decode``), so the per-head keys are never built there.
 """
 from repro.configs.base import ModelConfig, register
 

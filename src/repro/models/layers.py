@@ -401,42 +401,36 @@ def mla_attend(p: Params, cfg, x: jax.Array, positions: jax.Array, *,
 def mla_decode(p: Params, cfg, x: jax.Array, latent_cache: jax.Array,
                krope_cache: jax.Array, kv_len: jax.Array,
                positions: jax.Array) -> jax.Array:
-    """Decode over the latent cache.
+    """Single-token attention over the latent cache, in latent space.
 
-    latent_cache: (B, Lmax, R); krope_cache: (B, Lmax, rope_d).
-    If cfg.mla_absorb: attention runs in latent space (absorbed W_uk/W_uv) —
-    the beyond-paper optimized path; otherwise K/V are re-materialized.
+    latent_cache: (B, Lmax, R); krope_cache: (B, Lmax, rope_d); kv_len: (B,).
+    W_uk is folded into the query and W_uv applied after the value
+    contraction, so no per-head K/V is built from the cache: the attention
+    of ``mla_attend`` reassociated (which materialises K/V, as there L
+    query tokens share each key). Contractions take the cache's dtype and
+    accumulate in f32, as ``decode_attention`` does.
     """
     B = x.shape[0]
     H, R = cfg.n_heads, cfg.kv_lora_rank
+    dt = latent_cache.dtype
+    f32 = jnp.float32
     q_nope, q_rope = mla_queries(p, cfg, x, positions)  # (B,1,H,*)
-    scale = 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
-    Lmax = latent_cache.shape[1]
-    kpos = lax.iota(jnp.int32, Lmax)[None, :]
-    if cfg.mla_absorb:
-        wk_b = p["wk_b"].reshape(R, H, cfg.qk_nope_dim)
-        q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, wk_b)  # (B,1,H,R)
-        s = jnp.einsum("bqhr,blr->bhql", q_lat.astype(jnp.float32),
-                       latent_cache.astype(jnp.float32))
-        s += jnp.einsum("bqhd,bld->bhql", q_rope.astype(jnp.float32),
-                        krope_cache.astype(jnp.float32))
-        s = s * scale
-        s = jnp.where((kpos < kv_len[:, None])[:, None, None, :], s, -jnp.inf)
-        pattn = jax.nn.softmax(s, axis=-1)
-        o_lat = jnp.einsum("bhql,blr->bqhr", pattn,
-                           latent_cache.astype(jnp.float32))  # (B,1,H,R)
-        wv_b = p["wv_b"].reshape(R, H, cfg.v_head_dim)
-        out = jnp.einsum("bqhr,rhd->bqhd", o_lat, wv_b.astype(jnp.float32))
-        out = out.astype(x.dtype)
-    else:
-        k_nope = (latent_cache @ p["wk_b"]).reshape(B, Lmax, H, cfg.qk_nope_dim)
-        v = (latent_cache @ p["wv_b"]).reshape(B, Lmax, H, cfg.v_head_dim)
-        k = jnp.concatenate(
-            [k_nope,
-             jnp.broadcast_to(krope_cache[:, :, None, :], (B, Lmax, H, cfg.qk_rope_dim))],
-            axis=-1)
-        q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        out = decode_attention(q, k, v, kv_len=kv_len)
+    wk_b = p["wk_b"].reshape(R, H, cfg.qk_nope_dim)
+    q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], wk_b,
+                       preferred_element_type=f32).astype(dt)  # (B,H,R)
+    s = jnp.einsum("bhr,blr->bhl", q_lat, latent_cache,
+                   preferred_element_type=f32)
+    s += jnp.einsum("bhd,bld->bhl", q_rope[:, 0], krope_cache,
+                    preferred_element_type=f32)
+    s *= 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    kpos = lax.iota(jnp.int32, latent_cache.shape[1])[None, :]
+    s = jnp.where((kpos < kv_len[:, None])[:, None, :], s, -jnp.inf)
+    pattn = jax.nn.softmax(s, axis=-1)
+    o_lat = jnp.einsum("bhl,blr->bhr", pattn.astype(dt), latent_cache,
+                       preferred_element_type=f32)  # (B,H,R)
+    wv_b = p["wv_b"].reshape(R, H, cfg.v_head_dim)
+    out = jnp.einsum("bhr,rhd->bhd", o_lat.astype(dt), wv_b,
+                     preferred_element_type=f32).astype(x.dtype)
     return out.reshape(B, 1, -1) @ p["wo"]
 
 
