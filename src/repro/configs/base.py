@@ -69,7 +69,15 @@ class ModelConfig:
     n_shared_experts: int = 0
     d_ff_expert: int = 0
     first_dense_layers: int = 0  # deepseek: layer 0 dense
-    capacity_factor: float = 1.25
+    # gating (DeepSeek-V2's group_limited_greedy): the experts form n_group
+    # contiguous groups, the topk_group groups whose best expert scores
+    # highest are kept, top_k experts are taken among them; the gates are
+    # renormalised over the top_k (norm_topk_prob) and then scaled
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    capacity_factor: float = 1.25  # training only; the served path is dropless
     moe_impl: str = "scatter"  # scatter | einsum | shard_map
     # token-chunked MoE dispatch: bound the (E, C, d) buffer by processing
     # at most this many tokens per scan step (0 = single shot). §Perf A1.
@@ -162,6 +170,8 @@ class ModelConfig:
                       n_shared_experts=min(1, self.n_shared_experts),
                       first_dense_layers=min(1, self.first_dense_layers),
                       capacity_factor=8.0)
+            if self.n_group > 1:    # keep the group rule: 2 groups, 1 kept
+                kw.update(n_group=2, topk_group=1)
         if self.ssm_kind == "rwkv6":  # needs H*K == d_model
             kw.update(ssm_heads=4, ssm_head_dim=16)
         elif self.ssm_kind == "mamba2":  # needs H*P == d_inner

@@ -1,5 +1,9 @@
 """deepseek-v2-236b [moe] — MLA kv_lora=512, 2 shared + 160 routed top-6.
 [arXiv:2405.04434; hf]
+
+Routing is group_limited_greedy: softmax over the 160 router outputs, the
+3 of 8 groups (20 experts each) with the best top score kept, the top 6
+experts among them, gates not renormalised and scaled by 16.
 """
 from repro.configs.base import ModelConfig, register
 
@@ -23,6 +27,10 @@ CONFIG = register(ModelConfig(
     top_k=6,
     n_shared_experts=2,
     first_dense_layers=1,
+    n_group=8,
+    topk_group=3,
+    norm_topk_prob=False,
+    routed_scaling_factor=16.0,
     rope_theta=10_000.0,
     act="silu",
     skip_shapes={

@@ -487,12 +487,28 @@ def moe_init(key, cfg, dtype) -> Params:
     return p
 
 
-def moe_gating(logits: jax.Array, top_k: int, renormalize: bool = True):
-    """Returns (gates (T,k), idx (T,k), aux_loss scalar)."""
+def moe_gating(logits: jax.Array, top_k: int, renormalize: bool = True,
+               n_group: int = 1, topk_group: int = 1, scale: float = 1.0):
+    """Returns (gates (T,k), idx (T,k), aux_loss scalar).
+
+    Softmax over all E router outputs in f32. With n_group > 1 the experts
+    form n_group contiguous groups, a group scores its best expert, and
+    only the experts of the topk_group best groups can be taken
+    (DeepSeek-V2's group_limited_greedy). The gates are the top_k softmax
+    scores, renormalised over them if ``renormalize``, times ``scale``.
+    """
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gates, idx = lax.top_k(probs, top_k)
+    scores = probs
+    if n_group > 1:
+        T, E = probs.shape
+        grouped = probs.reshape(T, n_group, E // n_group)
+        _, best = lax.top_k(jnp.max(grouped, axis=-1), topk_group)
+        kept = jnp.any(jax.nn.one_hot(best, n_group, dtype=jnp.bool_), axis=1)
+        scores = jnp.where(kept[:, :, None], grouped, 0.0).reshape(T, E)
+    gates, idx = lax.top_k(scores, top_k)
     if renormalize:
         gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+    gates = gates * scale
     # load-balancing aux loss (Switch-style): E * sum_e f_e * p_e
     E = logits.shape[-1]
     me = jnp.mean(probs, axis=0)
@@ -512,13 +528,14 @@ def set_shard_mesh(mesh) -> None:
     _SHARD_MESH[0] = mesh
 
 
-def moe_apply_shard_map(p: Params, cfg, x: jax.Array
-                        ) -> tuple[jax.Array, jax.Array]:
+def moe_apply_shard_map(p: Params, cfg, x: jax.Array, dropless: bool = False
+                        ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Per-shard MoE dispatch (§Perf A3): tokens stay sharded over the DP
     axes through dispatch — each shard scatters only its LOCAL tokens into
     a local-capacity (E, C_loc, d) buffer, so no dispatch-buffer
     all-reduce crosses the wire. Expert ffn dims stay TP over "model";
     the combine's partial sums psum over "model" exactly like a dense MLP.
+    Returns what ``_moe`` returns, the routing counter over all experts.
     """
     mesh = _SHARD_MESH[0]
     if mesh is None or not mesh.axis_names:
@@ -526,7 +543,7 @@ def moe_apply_shard_map(p: Params, cfg, x: jax.Array
     dp = tuple(a for a in cfg.act_dp
                if mesh is not None and a in mesh.axis_names)
     if not dp or "model" not in getattr(mesh, "axis_names", ()):
-        return moe_apply(p, cfg.replace(moe_impl="scatter"), x)
+        return _moe(p, cfg.replace(moe_impl="scatter"), x, None, dropless)
     dp_ax = dp if len(dp) > 1 else dp[0]
     local_cfg = cfg.replace(moe_impl="scatter", act_dp=())
     from jax.sharding import PartitionSpec as P
@@ -534,15 +551,15 @@ def moe_apply_shard_map(p: Params, cfg, x: jax.Array
     ep = cfg.n_experts % tp == 0 and cfg.n_experts >= tp  # expert parallel
 
     def kern(p_local, x_local):
-        if ep:   # experts sharded over "model": dispatch to local range
-            lo = lax.axis_index("model") * (cfg.n_experts // tp)
-            y, aux = moe_apply(p_local, local_cfg, x_local, expert_lo=lo)
-        else:    # experts whole, ffn dim sliced over "model"
-            y, aux = moe_apply(p_local, local_cfg, x_local)
+        # EP: experts sharded over "model", dispatch to the local range;
+        # else experts whole, ffn dim sliced over "model"
+        lo = lax.axis_index("model") * (cfg.n_experts // tp) if ep else None
+        y, aux, routed = _moe(p_local, local_cfg, x_local, lo, dropless)
         y = jax.lax.psum(y, "model")  # combine: EP partial outputs and/or
         #                               TP ffn partial sums (+ shared)
         aux = jax.lax.pmean(aux, dp_ax)
-        return y, aux
+        routed = jax.lax.psum(routed, dp_ax)
+        return y, aux, routed
 
     if ep:
         pspecs = {"router": P(), "w_gate": P("model", None, None),
@@ -558,7 +575,8 @@ def moe_apply_shard_map(p: Params, cfg, x: jax.Array
                             for k in p["shared"]}
     fn = jax.shard_map(kern, mesh=mesh,
                        in_specs=(pspecs, P(dp_ax, None, None)),
-                       out_specs=(P(dp_ax, None, None), P()),
+                       out_specs=(P(dp_ax, None, None), P(),
+                                  P("model") if ep else P()),
                        check_vma=False)
     return fn(p, x)
 
@@ -566,19 +584,43 @@ def moe_apply_shard_map(p: Params, cfg, x: jax.Array
 def moe_apply(p: Params, cfg, x: jax.Array,
               expert_lo: jax.Array | None = None
               ) -> tuple[jax.Array, jax.Array]:
-    """x: (B, L, d) -> (out, aux_loss).
+    """Training path: x (B, L, d) -> (out, aux_loss), with each expert
+    taking at most its static capacity (``cfg.capacity_factor``) of
+    assignments; the overflow is dropped. See ``_moe``."""
+    out, aux, _ = _moe(p, cfg, x, expert_lo, dropless=False)
+    return out, aux
 
-    expert_lo: when set (inside the shard_map EP path), p holds only the
-    experts [expert_lo, expert_lo + len(w_gate)); assignments outside the
-    range go to the trash slot and contribute zero to this shard's output
-    (the cross-shard psum completes them).
 
-    Sort-free scatter dispatch with static capacity:
-      1. router -> top-k experts per token
+def moe_serve(p: Params, cfg, x: jax.Array,
+              expert_lo: jax.Array | None = None
+              ) -> tuple[jax.Array, jax.Array]:
+    """Served path (prefill, decode): x (B, L, d) -> (out, routed), where
+    no assignment is dropped, so a token's output does not depend on the
+    other tokens of its batch. ``routed`` (E_held,) int32 counts the
+    assignments each held expert received. See ``_moe``."""
+    out, _, routed = _moe(p, cfg, x, expert_lo, dropless=True)
+    return out, routed
+
+
+def _moe(p: Params, cfg, x: jax.Array, expert_lo: jax.Array | None,
+         dropless: bool) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """x: (B, L, d) -> (out, aux_loss, routed).
+
+    The layer holds the experts [expert_lo, expert_lo + len(w_gate)) of
+    the cfg.n_experts it routes over (expert_lo None: from 0). Assignments
+    outside the range go to the trash slot and contribute zero (under the
+    shard_map EP path the cross-shard psum completes them; on one chip
+    they are the absent chips' part). The shared experts are computed in
+    full. ``routed`` counts the assignments each held expert received.
+
+    Sort-free scatter dispatch with static capacity C:
+      1. router (f32 logits) -> top-k experts per token (``moe_gating``)
       2. per-(token,k) slot position inside its expert via sorted ranking
       3. scatter tokens into an (E, C, d) buffer (overflow dropped)
       4. grouped expert FFN as batched matmul (MXU-shaped)
       5. gather back + gate-weighted combine
+    Dropless, C is the number of tokens: a token takes an expert at most
+    once, so no expert can overflow. Else C comes from capacity_factor.
     The (E, C, d) buffer is sharded over the `model` axis (expert
     parallelism); with activations replicated over `model`, dispatch needs
     no all-to-all and combine rides the existing TP psum.
@@ -588,59 +630,69 @@ def moe_apply(p: Params, cfg, x: jax.Array,
     -> fits, flops unchanged).
     """
     if cfg.moe_impl == "shard_map" and cfg.act_dp:
-        return moe_apply_shard_map(p, cfg, x)
+        return moe_apply_shard_map(p, cfg, x, dropless)
     B, L, d = x.shape
     T = B * L
+    E_loc = p["w_gate"].shape[0]          # < E: this chip's share
     chunk = cfg.moe_chunk_tokens
     if chunk and T > chunk:
         while T % chunk:                  # largest divisor <= requested
             chunk -= 1
         xt = x.reshape(T // chunk, 1, chunk, d)
 
-        def body(aux, xc):
-            yc, a = moe_apply(p, cfg.replace(moe_chunk_tokens=0), xc,
-                              expert_lo)
-            return aux + a, yc
+        def body(acc, xc):
+            yc, a, r = _moe(p, cfg.replace(moe_chunk_tokens=0), xc,
+                            expert_lo, dropless)
+            return (acc[0] + a, acc[1] + r), yc
 
-        aux, y = lax.scan(body, jnp.zeros((), jnp.float32), xt)
-        return y.reshape(B, L, d), aux / (T // chunk)
+        (aux, routed), y = lax.scan(
+            body, (jnp.zeros((), jnp.float32), jnp.zeros((E_loc,), jnp.int32)),
+            xt)
+        return y.reshape(B, L, d), aux / (T // chunk), routed
     E, k = cfg.n_experts, cfg.top_k
-    E_loc = p["w_gate"].shape[0]          # < E inside the EP shard_map
-    C = max(8, int(math.ceil(cfg.capacity_factor * T * k / E / 8.0)) * 8)
+    C = T if dropless else \
+        max(8, int(math.ceil(cfg.capacity_factor * T * k / E / 8.0)) * 8)
     xt = x.reshape(T, d)
-    logits = xt @ p["router"]
-    gates, idx, aux = moe_gating(logits, k)
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(xt, p["router"], preferred_element_type=jnp.float32)
+        gates, idx, aux = moe_gating(logits, k, cfg.norm_topk_prob,
+                                     cfg.n_group, cfg.topk_group,
+                                     cfg.routed_scaling_factor)
+        flat_e = idx.reshape(-1)  # (T*k,)
+        # rank of each assignment within its expert (stable by token order)
+        order = jnp.argsort(flat_e, stable=True)  # (T*k,)
+        ranks_sorted = lax.iota(jnp.int32, T * k)
+        counts = jnp.bincount(flat_e, length=E)
+        starts = jnp.concatenate([jnp.zeros((1,), counts.dtype),
+                                  jnp.cumsum(counts)[:-1]])
+        pos_sorted = ranks_sorted - starts[flat_e[order]]
+        pos = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            pos_sorted.astype(jnp.int32))
+        keep = pos < C
+        lo = 0 if expert_lo is None else expert_lo
+        routed = lax.dynamic_slice(counts, (lo,), (E_loc,)).astype(jnp.int32)
+        le = flat_e - lo
+        if expert_lo is not None or E_loc != E:
+            keep = keep & (le >= 0) & (le < E_loc)
+        slot = jnp.where(keep, le * C + pos, E_loc * C)  # E_loc*C = trash
 
-    flat_e = idx.reshape(-1)  # (T*k,)
-    # rank of each assignment within its expert (stable by token order)
-    order = jnp.argsort(flat_e, stable=True)  # (T*k,)
-    ranks_sorted = lax.iota(jnp.int32, T * k)
-    counts = jnp.bincount(flat_e, length=E)
-    starts = jnp.concatenate([jnp.zeros((1,), counts.dtype),
-                              jnp.cumsum(counts)[:-1]])
-    pos_sorted = ranks_sorted - starts[flat_e[order]]
-    pos = jnp.zeros((T * k,), jnp.int32).at[order].set(pos_sorted.astype(jnp.int32))
-    keep = pos < C
-    le = flat_e if expert_lo is None else flat_e - expert_lo
-    if expert_lo is not None or E_loc != E:
-        keep = keep & (le >= 0) & (le < E_loc)
-    slot = jnp.where(keep, le * C + pos, E_loc * C)  # E_loc*C = trash slot
+        x_rep = jnp.repeat(xt, k, axis=0)  # (T*k, d)
+        buf = jnp.zeros((E_loc * C + 1, d), x.dtype).at[slot].add(x_rep)
+        buf = buf[:-1].reshape(E_loc, C, d)
 
-    x_rep = jnp.repeat(xt, k, axis=0)  # (T*k, d)
-    buf = jnp.zeros((E_loc * C + 1, d), x.dtype).at[slot].add(x_rep)
-    buf = buf[:-1].reshape(E_loc, C, d)
+    with jax.named_scope("moe.experts"):
+        a = _ACTS[cfg.act]
+        h = a(jnp.einsum("ecd,edf->ecf", buf, p["w_gate"])) * jnp.einsum(
+            "ecd,edf->ecf", buf, p["w_up"])
+        y = jnp.einsum("ecf,efd->ecd", h, p["w_down"])  # (E_loc, C, d)
 
-    a = _ACTS[cfg.act]
-    h = a(jnp.einsum("ecd,edf->ecf", buf, p["w_gate"])) * jnp.einsum(
-        "ecd,edf->ecf", buf, p["w_up"])
-    y = jnp.einsum("ecf,efd->ecd", h, p["w_down"])  # (E_loc, C, d)
-
-    y_flat = jnp.concatenate([y.reshape(E_loc * C, d),
-                              jnp.zeros((1, d), y.dtype)], axis=0)
-    y_tok = y_flat[slot]  # (T*k, d) — dropped/foreign tokens read zeros
-    y_tok = y_tok * gates.reshape(-1, 1).astype(y_tok.dtype)
-    out = jnp.sum(y_tok.reshape(T, k, d), axis=1)
+        y_flat = jnp.concatenate([y.reshape(E_loc * C, d),
+                                  jnp.zeros((1, d), y.dtype)], axis=0)
+        y_tok = y_flat[slot]  # (T*k, d) — dropped/foreign tokens read zeros
+        y_tok = y_tok * gates.reshape(-1, 1).astype(y_tok.dtype)
+        out = jnp.sum(y_tok.reshape(T, k, d), axis=1)
 
     if cfg.n_shared_experts:
-        out = out + mlp(p["shared"], xt, cfg.act)
-    return out.reshape(B, L, d), aux
+        with jax.named_scope("moe.shared"):
+            out = out + mlp(p["shared"], xt, cfg.act)
+    return out.reshape(B, L, d), aux, routed

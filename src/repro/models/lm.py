@@ -9,6 +9,9 @@ Public entry points:
     prefill(params, cfg, batch, cache)          -> (last_logits, cache)
     decode_step(params, cfg, tokens, cache, pos)-> (logits, cache)
 
+With ``routing=True`` prefill and decode_step also return the routing
+counter of an MoE model (None for one without experts).
+
 `batch` is a dict: tokens (B, L) int32, plus modality-stub inputs
 (patch_embed for VLM, frames for audio) per DESIGN.md §5.
 """
@@ -433,13 +436,22 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 
 
 @jax.named_scope("lm.prefill")
-def prefill(p: Params, cfg: ModelConfig, batch: dict, cache: Params
-            ) -> tuple[jax.Array, Params]:
+def prefill(p: Params, cfg: ModelConfig, batch: dict, cache: Params,
+            routing: bool = False) -> tuple:
     """Process the full prompt; fill the cache; return last-position logits.
 
     For SWA archs the cache keeps the trailing `window` positions. SSM /
     hybrid archs run their chunked forward and keep only final states.
+    MoE layers drop no assignment (``layers.moe_serve``). With
+    ``routing``, also return the assignments each held expert received in
+    each MoE layer over the batch's tokens, (n_moe_layers, E_held) int32,
+    or None for a model without experts.
     """
+    logits, cache, routed = _prefill(p, cfg, batch, cache)
+    return (logits, cache, routed) if routing else (logits, cache)
+
+
+def _prefill(p: Params, cfg: ModelConfig, batch: dict, cache: Params):
     x, prefix_len = _assemble_input(p, cfg, batch)
     B, Lx, _ = x.shape
     positions = jnp.arange(Lx)
@@ -486,16 +498,17 @@ def prefill(p: Params, cfg: ModelConfig, batch: dict, cache: Params
                 x = x + a.reshape(B, Lx, -1) @ bp["xattn"]["wo"]
                 kv["xk"], kv["xv"] = mk, mv
             h = _norm(cfg, bp["ln2"], x)
+            routed = None
             if cfg.is_moe and "router" in bp["mlp"]:
-                m, _ = L.moe_apply(bp["mlp"], cfg, h)
+                m, routed = L.moe_serve(bp["mlp"], cfg, h)
             else:
                 m = L.mlp(bp["mlp"], h, cfg.act)
-            return x + m, kv
+            return x + m, (kv, routed)
 
         new_cache = dict(cache)
         x_cur = x
         for i, blk in enumerate(p.get("dense0", [])):
-            x_cur, kv = layer(x_cur, blk)
+            x_cur, (kv, _) = layer(x_cur, blk)
             for key in kv:
                 new_cache[key] = _store(new_cache[key], kv[key][None], i)
 
@@ -503,11 +516,11 @@ def prefill(p: Params, cfg: ModelConfig, batch: dict, cache: Params
             return layer(x, bp)
 
         fn = jax.checkpoint(body) if cfg.remat else body
-        x_cur, kvs = lax.scan(fn, x_cur, p["blocks"])
+        x_cur, (kvs, routed) = lax.scan(fn, x_cur, p["blocks"])
         for key in kvs:
             new_cache[key] = _store(new_cache[key], kvs[key], n_dense0)
         logits = unembed(p, cfg, _norm(cfg, p["final_norm"], x_cur[:, -1:]))
-        return logits[:, 0], new_cache
+        return logits[:, 0], new_cache, routed
 
     if kind == "rwkv6":
         def body(x, inp):
@@ -519,7 +532,7 @@ def prefill(p: Params, cfg: ModelConfig, batch: dict, cache: Params
         fn = jax.checkpoint(body) if cfg.remat else body
         x_cur, states = lax.scan(fn, x, p["blocks"])
         logits = unembed(p, cfg, _norm(cfg, p["final_norm"], x_cur[:, -1:]))
-        return logits[:, 0], states
+        return logits[:, 0], states, None
 
     if kind == "mamba2":
         x0 = x
@@ -560,7 +573,7 @@ def prefill(p: Params, cfg: ModelConfig, batch: dict, cache: Params
         if every:
             new_cache["ak"], new_cache["av"] = ak, av
         logits = unembed(p, cfg, _norm(cfg, p["final_norm"], x_cur[:, -1:]))
-        return logits[:, 0], new_cache
+        return logits[:, 0], new_cache, None
 
     raise ValueError(kind)
 
@@ -572,11 +585,18 @@ def prefill(p: Params, cfg: ModelConfig, batch: dict, cache: Params
 
 @jax.named_scope("lm.decode_step")
 def decode_step(p: Params, cfg: ModelConfig, tokens: jax.Array, cache: Params,
-                pos: jax.Array, kv_len: Optional[jax.Array] = None
-                ) -> tuple[jax.Array, Params]:
+                pos: jax.Array, kv_len: Optional[jax.Array] = None,
+                routing: bool = False) -> tuple:
     """One decode step. tokens: (B,1); pos: scalar int32 (write index);
     kv_len: (B,) valid lengths (defaults to pos+1). Returns
-    (logits (B,V), cache)."""
+    (logits (B,V), cache), and with ``routing`` the routing counter as
+    ``prefill`` gives it, over the B tokens."""
+    logits, cache, routed = _decode_step(p, cfg, tokens, cache, pos, kv_len)
+    return (logits, cache, routed) if routing else (logits, cache)
+
+
+def _decode_step(p: Params, cfg: ModelConfig, tokens: jax.Array,
+                 cache: Params, pos: jax.Array, kv_len: Optional[jax.Array]):
     B = tokens.shape[0]
     x = embed_tokens(p, cfg, tokens)
     if kv_len is None:
@@ -635,25 +655,27 @@ def decode_step(p: Params, cfg: ModelConfig, tokens: jax.Array, cache: Params,
                 a = L.decode_attention(q, c["xk"], c["xv"], kv_len=enc_len)
                 x = x + a.reshape(B, 1, -1) @ bp["xattn"]["wo"]
             h = _norm(cfg, bp["ln2"], x)
+            routed = None
             if cfg.is_moe and "router" in bp["mlp"]:
-                m, _ = L.moe_apply(bp["mlp"], cfg, h)
+                m, routed = L.moe_serve(bp["mlp"], cfg, h)
             else:
                 m = L.mlp(bp["mlp"], h, cfg.act)
-            return x + m, c
+            return x + m, (c, routed)
 
         new_cache = dict(cache)
         x_cur = x
         n_dense0 = len(p.get("dense0", []))
         for i, blk in enumerate(p.get("dense0", [])):
             ci = jax.tree.map(lambda a: a[i], cache)
-            x_cur, ci = body(x_cur, (blk, ci))
+            x_cur, (ci, _) = body(x_cur, (blk, ci))
             for key in ci:
                 new_cache[key] = new_cache[key].at[i].set(ci[key])
         if n_dense0:
             rest = jax.tree.map(lambda a: a[n_dense0:], cache)
         else:
             rest = cache
-        x_cur, rest_new = lax.scan(body, x_cur, (p["blocks"], rest))
+        x_cur, (rest_new, routed) = lax.scan(body, x_cur,
+                                             (p["blocks"], rest))
         for key in rest_new:
             if n_dense0:
                 new_cache[key] = lax.dynamic_update_slice(
@@ -662,7 +684,7 @@ def decode_step(p: Params, cfg: ModelConfig, tokens: jax.Array, cache: Params,
             else:
                 new_cache[key] = rest_new[key]
         logits = unembed(p, cfg, _norm(cfg, p["final_norm"], x_cur))
-        return logits[:, 0], new_cache
+        return logits[:, 0], new_cache, routed
 
     if kind == "rwkv6":
         def body(x, inp):
@@ -672,7 +694,7 @@ def decode_step(p: Params, cfg: ModelConfig, tokens: jax.Array, cache: Params,
 
         x_cur, states = lax.scan(body, x, (p["blocks"], cache))
         logits = unembed(p, cfg, _norm(cfg, p["final_norm"], x_cur))
-        return logits[:, 0], states
+        return logits[:, 0], states, None
 
     if kind == "mamba2":
         every, n = cfg.attn_every, cfg.n_layers
@@ -713,6 +735,6 @@ def decode_step(p: Params, cfg: ModelConfig, tokens: jax.Array, cache: Params,
         if every:
             new_cache["ak"], new_cache["av"] = ak, av
         logits = unembed(p, cfg, _norm(cfg, p["final_norm"], x_cur))
-        return logits[:, 0], new_cache
+        return logits[:, 0], new_cache, None
 
     raise ValueError(kind)

@@ -134,7 +134,13 @@ class AnalyticEngine:
 class ModelEngine:
     """Slot-based engine over a zoo model: jitted prefill into a slot +
     per-slot vmapped decode (each slot has its own position/kv_len, the
-    requirement for continuous batching)."""
+    requirement for continuous batching).
+
+    For an MoE model each step also returns its routing counter, the
+    assignments each held expert received in each MoE layer, fetched with
+    the step's tokens: prefills add theirs to ``prefill_routed``; a decode
+    step's, over its active slots, is noted on its ``engine.decode`` span
+    while spans record (``repro.trace``)."""
 
     def __init__(self, params, cfg: ModelConfig, n_slots: int = 4,
                  max_len: int = 256):
@@ -144,7 +150,9 @@ class ModelEngine:
         self.cache = lm.init_cache(cfg, n_slots, max_len)
         self.pos = np.zeros(n_slots, np.int32)        # next write index
         self.active = np.zeros(n_slots, bool)
-        self._jit_prefill = jax.jit(partial(lm.prefill, cfg=cfg))
+        self.prefill_routed = None    # (n_moe_layers, E_held) running total
+        self._jit_prefill = jax.jit(partial(lm.prefill, cfg=cfg,
+                                            routing=True))
         # vmap decode over the slot axis: cache leaves are (n_layers, B, ...)
         cache_axes = jax.tree.map(lambda _: 1, self.cache)
 
@@ -152,13 +160,15 @@ class ModelEngine:
             # vmap strips the slot axis (axis 1 of every cache leaf);
             # decode_step expects an explicit batch dim -> re-insert B=1
             cache1 = jax.tree.map(lambda a: a[:, None], cache)
-            logits, new_cache = lm.decode_step(
+            logits, new_cache, routed = lm.decode_step(
                 params, cfg, tokens[None], cache1, pos,
-                kv_len=(pos + 1)[None])
-            return logits[0], jax.tree.map(lambda a: a[:, 0], new_cache)
+                kv_len=(pos + 1)[None], routing=True)
+            return (logits[0], jax.tree.map(lambda a: a[:, 0], new_cache),
+                    routed)
 
         self._jit_decode = jax.jit(jax.vmap(
-            _one, in_axes=(None, 0, cache_axes, 0), out_axes=(0, cache_axes)))
+            _one, in_axes=(None, 0, cache_axes, 0),
+            out_axes=(0, cache_axes, 0)))
 
     def free_slots(self) -> list[int]:
         return [i for i in range(self.n_slots) if not self.active[i]]
@@ -169,8 +179,8 @@ class ModelEngine:
         with trace.span("engine.prefill", n=lp):
             batch = {"tokens": jnp.asarray(tokens, jnp.int32)[None]}
             cache1 = self.lm.init_cache(self.cfg, 1, self.max_len)
-            logits, cache1 = self._jit_prefill(self.params, batch=batch,
-                                               cache=cache1)
+            logits, cache1, routed = self._jit_prefill(
+                self.params, batch=batch, cache=cache1)
 
             def place(full, one):
                 idx = [0] * full.ndim
@@ -183,7 +193,12 @@ class ModelEngine:
             self.active[slot] = True
             first = jnp.argmax(logits[0])
             with trace.span("engine.prefill.wait"):
-                return int(first)
+                first, routed = jax.device_get((first, routed))
+            if routed is not None:
+                if self.prefill_routed is None:
+                    self.prefill_routed = np.zeros(routed.shape, np.int64)
+                self.prefill_routed += routed
+            return int(first)
 
     def decode_active(self, tokens: np.ndarray) -> np.ndarray:
         """One decode step for every slot (inactive slots decode garbage
@@ -195,14 +210,20 @@ class ModelEngine:
         (pos below, tokens by the scheduler's retire loop) while the
         async computation may still be reading them — a data race that
         surfaced as run-to-run nondeterministic decode output."""
-        with trace.span("engine.decode"):
-            logits, self.cache = self._jit_decode(
+        with trace.span("engine.decode") as sp:
+            logits, self.cache, routed = self._jit_decode(
                 self.params, jnp.array(tokens, jnp.int32)[:, None],
                 self.cache, jnp.array(self.pos))
-            self.pos[self.active] += 1
+            active = self.active.copy()
+            self.pos[active] += 1
             nxt = jnp.argmax(logits, axis=-1)
             with trace.span("engine.decode.wait"):
-                return np.asarray(nxt)
+                nxt, routed = jax.device_get((nxt, routed))
+            if routed is not None and trace.enabled():
+                # per slot (n_slots, n_moe_layers, E_held): idle slots'
+                # tokens route too and do not count
+                sp.note(routed=routed[active].sum(axis=0))
+            return nxt
 
     def release(self, slot: int) -> None:
         self.active[slot] = False

@@ -19,6 +19,10 @@ span records nothing and costs one ``is_enabled()`` call. Nothing under
         with trace.span("lookup.wait"):
             out = jax.device_get(x)
 
+    with trace.span("engine.decode") as sp:
+        ...
+        sp.note(routed=counts)      # a count known only inside the span
+
     with trace.recording():
         serve()
     for s in trace.spans(): ...
@@ -102,6 +106,9 @@ class _Off:
     def __exit__(self, *exc):
         return False
 
+    def note(self, **counts):
+        """Add counts to the span's payload (records nothing here)."""
+
 
 _OFF = _Off()
 
@@ -117,6 +124,8 @@ class _Clocked:
     def __exit__(self, *exc):
         self.t1 = clock()
         return False
+
+    note = _Off.note
 
 
 class _Recording:
@@ -148,6 +157,10 @@ class _Recording:
                                       self.parent, int(self.key),
                                       self.counts)
         return False
+
+    def note(self, **counts):
+        """Add counts to the span's payload."""
+        self.counts.update(counts)
 
 
 @contextmanager

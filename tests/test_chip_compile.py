@@ -99,3 +99,27 @@ def test_embedder_encode_compiles_for_v5e(one_chip):
         params, tokens=_spec((BATCH, 16), jnp.int32, one_chip)).compile()
     out = compiled.out_info
     assert out.shape == (BATCH, cfg.d_model) and out.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("tokens", [1, 64])
+def test_served_expert_layer_compiles_for_v5e(one_chip, tokens):
+    """The served expert layer at deepseek-v2-236b's published widths, as
+    one chip's share (routing group 0, 20 of the 160 experts it routes
+    over), for a decode token and a 64-token prompt: dropless, so its
+    buffer holds every token in every held expert."""
+    from repro.configs.base import get_config
+    from repro.models import layers as L
+    cfg = get_config("deepseek-v2-236b")
+    params = jax.eval_shape(partial(L.moe_init, cfg=cfg, dtype=jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    params = {k: (v if k in ("router", "shared")
+                  else jax.ShapeDtypeStruct((20,) + v.shape[1:], v.dtype))
+              for k, v in params.items()}
+    params = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                          params)
+    compiled = jax.jit(partial(L.moe_serve, cfg=cfg)).lower(
+        params, x=_spec((1, tokens, cfg.d_model), jnp.bfloat16,
+                        one_chip)).compile()
+    out, routed = compiled.out_info
+    assert out.shape == (1, tokens, cfg.d_model)
+    assert routed.shape == (20,) and routed.dtype == jnp.int32
