@@ -558,7 +558,6 @@ def moe_apply_shard_map(p: Params, cfg, x: jax.Array, dropless: bool = False
         y = jax.lax.psum(y, "model")  # combine: EP partial outputs and/or
         #                               TP ffn partial sums (+ shared)
         aux = jax.lax.pmean(aux, dp_ax)
-        routed = jax.lax.psum(routed, dp_ax)
         return y, aux, routed
 
     if ep:
@@ -576,7 +575,7 @@ def moe_apply_shard_map(p: Params, cfg, x: jax.Array, dropless: bool = False
     fn = jax.shard_map(kern, mesh=mesh,
                        in_specs=(pspecs, P(dp_ax, None, None)),
                        out_specs=(P(dp_ax, None, None), P(),
-                                  P("model") if ep else P()),
+                                  P(dp_ax, "model" if ep else None)),
                        check_vma=False)
     return fn(p, x)
 
@@ -596,8 +595,8 @@ def moe_serve(p: Params, cfg, x: jax.Array,
               ) -> tuple[jax.Array, jax.Array]:
     """Served path (prefill, decode): x (B, L, d) -> (out, routed), where
     no assignment is dropped, so a token's output does not depend on the
-    other tokens of its batch. ``routed`` (E_held,) int32 counts the
-    assignments each held expert received. See ``_moe``."""
+    other tokens of its batch. ``routed`` (B * L, E_held) int32 counts the
+    assignments each held expert received from each token. See ``_moe``."""
     out, _, routed = _moe(p, cfg, x, expert_lo, dropless=True)
     return out, routed
 
@@ -611,7 +610,8 @@ def _moe(p: Params, cfg, x: jax.Array, expert_lo: jax.Array | None,
     outside the range go to the trash slot and contribute zero (under the
     shard_map EP path the cross-shard psum completes them; on one chip
     they are the absent chips' part). The shared experts are computed in
-    full. ``routed`` counts the assignments each held expert received.
+    full. ``routed`` (B * L, E_held) counts the assignments each held
+    expert received from each token.
 
     Sort-free scatter dispatch with static capacity C:
       1. router (f32 logits) -> top-k experts per token (``moe_gating``)
@@ -640,15 +640,13 @@ def _moe(p: Params, cfg, x: jax.Array, expert_lo: jax.Array | None,
             chunk -= 1
         xt = x.reshape(T // chunk, 1, chunk, d)
 
-        def body(acc, xc):
+        def body(aux, xc):
             yc, a, r = _moe(p, cfg.replace(moe_chunk_tokens=0), xc,
                             expert_lo, dropless)
-            return (acc[0] + a, acc[1] + r), yc
+            return aux + a, (yc, r)
 
-        (aux, routed), y = lax.scan(
-            body, (jnp.zeros((), jnp.float32), jnp.zeros((E_loc,), jnp.int32)),
-            xt)
-        return y.reshape(B, L, d), aux / (T // chunk), routed
+        aux, (y, r) = lax.scan(body, jnp.zeros((), jnp.float32), xt)
+        return y.reshape(B, L, d), aux / (T // chunk), r.reshape(T, E_loc)
     E, k = cfg.n_experts, cfg.top_k
     C = T if dropless else \
         max(8, int(math.ceil(cfg.capacity_factor * T * k / E / 8.0)) * 8)
@@ -670,8 +668,10 @@ def _moe(p: Params, cfg, x: jax.Array, expert_lo: jax.Array | None,
             pos_sorted.astype(jnp.int32))
         keep = pos < C
         lo = 0 if expert_lo is None else expert_lo
-        routed = lax.dynamic_slice(counts, (lo,), (E_loc,)).astype(jnp.int32)
         le = flat_e - lo
+        # an expert outside the held range counts nowhere
+        routed = jax.nn.one_hot(le.reshape(T, k), E_loc,
+                                dtype=jnp.int32).sum(axis=1)
         if expert_lo is not None or E_loc != E:
             keep = keep & (le >= 0) & (le < E_loc)
         slot = jnp.where(keep, le * C + pos, E_loc * C)  # E_loc*C = trash

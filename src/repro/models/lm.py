@@ -193,9 +193,8 @@ def _zamba_shared_fwd(sp: Params, cfg, x, x0, inv: jax.Array, positions,
     if kv_cache is None:
         a = L.flash_attention(q, k, v, causal=True)
     else:
-        k_full, v_full = kv_cache
-        k_full = lax.dynamic_update_slice(k_full, k, (0, pos, 0, 0))
-        v_full = lax.dynamic_update_slice(v_full, v, (0, pos, 0, 0))
+        k_full = _write_row(kv_cache[0], k, pos)
+        v_full = _write_row(kv_cache[1], v, pos)
         a = L.decode_attention(q, k_full, v_full, kv_len=kv_len)
         k, v = k_full, v_full
     x = x + a.reshape(B, -1, H * Dh) @ sp["wo"]
@@ -365,6 +364,23 @@ def _store(cache_arr: jax.Array, kv: jax.Array, layer_offset: int = 0
     return lax.dynamic_update_slice(cache_arr, kv.astype(cache_arr.dtype), idx)
 
 
+def _write_row(leaf: jax.Array, row: jax.Array, write_pos: jax.Array
+               ) -> jax.Array:
+    """Write each batch row's new entry, row (B, 1, ...), into the cache
+    leaf (B, Lc, ...) at write_pos.
+
+    A scalar write_pos, shared by the rows, is one slice update. A (B,)
+    write_pos, a position per row, is a one-hot select over the position
+    axis: an indexed update at per-row positions lowers to a scatter,
+    which XLA runs as a loop over the rows."""
+    if jnp.ndim(write_pos) == 0:
+        return lax.dynamic_update_slice(
+            leaf, row, (0, write_pos) + (0,) * (leaf.ndim - 2))
+    at = lax.broadcasted_iota(jnp.int32, leaf.shape[:2], 1) \
+        == write_pos[:, None]
+    return jnp.where(at.reshape(at.shape + (1,) * (leaf.ndim - 2)), row, leaf)
+
+
 def kv_quant(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """(…, H, D) -> (int8 codes, f16 per-(…, H) symmetric scale)."""
     amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1)
@@ -501,6 +517,7 @@ def _prefill(p: Params, cfg: ModelConfig, batch: dict, cache: Params):
             routed = None
             if cfg.is_moe and "router" in bp["mlp"]:
                 m, routed = L.moe_serve(bp["mlp"], cfg, h)
+                routed = routed.sum(axis=0)     # over the batch's tokens
             else:
                 m = L.mlp(bp["mlp"], h, cfg.act)
             return x + m, (kv, routed)
@@ -587,10 +604,12 @@ def _prefill(p: Params, cfg: ModelConfig, batch: dict, cache: Params):
 def decode_step(p: Params, cfg: ModelConfig, tokens: jax.Array, cache: Params,
                 pos: jax.Array, kv_len: Optional[jax.Array] = None,
                 routing: bool = False) -> tuple:
-    """One decode step. tokens: (B,1); pos: scalar int32 (write index);
-    kv_len: (B,) valid lengths (defaults to pos+1). Returns
-    (logits (B,V), cache), and with ``routing`` the routing counter as
-    ``prefill`` gives it, over the B tokens."""
+    """One decode step. tokens: (B,1); pos: int32 write index and rotary
+    position, a scalar shared by the rows or (B,), one per row; kv_len:
+    (B,) valid lengths (defaults to pos+1). Returns (logits (B,V), cache),
+    and with ``routing`` the routing counter per token,
+    (B, n_moe_layers, E_held) int32: the assignments each held expert
+    received from each row's token (None for a model without experts)."""
     logits, cache, routed = _decode_step(p, cfg, tokens, cache, pos, kv_len)
     return (logits, cache, routed) if routing else (logits, cache)
 
@@ -602,7 +621,7 @@ def _decode_step(p: Params, cfg: ModelConfig, tokens: jax.Array,
     if kv_len is None:
         kv_len = jnp.full((B,), pos + 1, jnp.int32)
     kind = _main_kind(cfg)
-    positions = pos[None] if jnp.ndim(pos) == 0 else pos
+    positions = pos[None, None] if jnp.ndim(pos) == 0 else pos[:, None]
     Lc = cache[next(iter(cache))].shape[2] if kind in ("dense", "moe", "decoder") else 0
     write_pos = jnp.mod(pos, Lc) if cfg.window is not None else pos
 
@@ -613,34 +632,25 @@ def _decode_step(p: Params, cfg: ModelConfig, tokens: jax.Array,
             bp, c = inp
             h = _norm(cfg, bp["ln1"], x)
             if cfg.attn_kind == "mla":
-                latent, krope = L.mla_latent(bp["attn"], cfg, h,
-                                             positions[None, :])
-                c["latent"] = lax.dynamic_update_slice(
-                    c["latent"], latent, (0, write_pos, 0))
-                c["krope"] = lax.dynamic_update_slice(
-                    c["krope"], krope, (0, write_pos, 0))
+                latent, krope = L.mla_latent(bp["attn"], cfg, h, positions)
+                c["latent"] = _write_row(c["latent"], latent, write_pos)
+                c["krope"] = _write_row(c["krope"], krope, write_pos)
                 a = L.mla_decode(bp["attn"], cfg, h, c["latent"], c["krope"],
-                                 eff_len, positions[None, :])
+                                 eff_len, positions)
             else:
-                q, k, v = L.gqa_qkv(bp["attn"], cfg, h, positions[None, :])
+                q, k, v = L.gqa_qkv(bp["attn"], cfg, h, positions)
                 if cfg.kv_dtype == "int8":
                     kq, ks = kv_quant(k)
                     vq, vs = kv_quant(v)
-                    c["k"] = lax.dynamic_update_slice(c["k"], kq,
-                                                      (0, write_pos, 0, 0))
-                    c["v"] = lax.dynamic_update_slice(c["v"], vq,
-                                                      (0, write_pos, 0, 0))
-                    c["k_scale"] = lax.dynamic_update_slice(
-                        c["k_scale"], ks, (0, write_pos, 0))
-                    c["v_scale"] = lax.dynamic_update_slice(
-                        c["v_scale"], vs, (0, write_pos, 0))
+                    c["k"] = _write_row(c["k"], kq, write_pos)
+                    c["v"] = _write_row(c["v"], vq, write_pos)
+                    c["k_scale"] = _write_row(c["k_scale"], ks, write_pos)
+                    c["v_scale"] = _write_row(c["v_scale"], vs, write_pos)
                     k_full = kv_dequant(c["k"], c["k_scale"], h.dtype)
                     v_full = kv_dequant(c["v"], c["v_scale"], h.dtype)
                 else:
-                    c["k"] = lax.dynamic_update_slice(c["k"], k,
-                                                      (0, write_pos, 0, 0))
-                    c["v"] = lax.dynamic_update_slice(c["v"], v,
-                                                      (0, write_pos, 0, 0))
+                    c["k"] = _write_row(c["k"], k, write_pos)
+                    c["v"] = _write_row(c["v"], v, write_pos)
                     k_full, v_full = c["k"], c["v"]
                 a = L.decode_attention(
                     q, k_full, v_full, kv_len=eff_len,
@@ -649,7 +659,7 @@ def _decode_step(p: Params, cfg: ModelConfig, tokens: jax.Array,
             x = x + a
             if cfg.is_encoder_decoder:
                 h = _norm(cfg, bp["ln_x"], x)
-                q, _, _ = L.gqa_qkv(bp["xattn"], cfg, h, positions[None, :],
+                q, _, _ = L.gqa_qkv(bp["xattn"], cfg, h, positions,
                                     rope=False)
                 enc_len = jnp.full((B,), c["xk"].shape[1], jnp.int32)
                 a = L.decode_attention(q, c["xk"], c["xv"], kv_len=enc_len)
@@ -684,6 +694,8 @@ def _decode_step(p: Params, cfg: ModelConfig, tokens: jax.Array,
             else:
                 new_cache[key] = rest_new[key]
         logits = unembed(p, cfg, _norm(cfg, p["final_norm"], x_cur))
+        if routed is not None:                  # (n_moe, B, E) -> per token
+            routed = jnp.swapaxes(routed, 0, 1)
         return logits[:, 0], new_cache, routed
 
     if kind == "rwkv6":
@@ -717,7 +729,7 @@ def _decode_step(p: Params, cfg: ModelConfig, tokens: jax.Array,
                 x, ak, av = args
                 ak_i, av_i = ak[inv], av[inv]
                 y, (k_new, v_new) = _zamba_shared_fwd(
-                    p["shared_attn"], cfg, x, x0, inv, positions[None, :],
+                    p["shared_attn"], cfg, x, x0, inv, positions,
                     kv_cache=(ak_i, av_i), pos=a_write, kv_len=kv_len)
                 ak = lax.dynamic_update_index_in_dim(ak, k_new, inv, 0)
                 av = lax.dynamic_update_index_in_dim(av, v_new, inv, 0)

@@ -8,9 +8,10 @@ Two tiers (DESIGN.md §9.2):
   used in the roofline analysis (197 TFLOP/s bf16, 819 GB/s HBM per chip).
   Drives the discrete-event SLO simulator.
 
-* ``ModelEngine`` — a real JAX model from the zoo behind jitted prefill +
-  per-slot vmapped decode, used by the runnable examples and the
-  continuous-batching scheduler.
+* ``ModelEngine`` — a real JAX model from the zoo behind jitted prefill
+  into a slot + one jitted decode step over all slots, each at its own
+  position, used by the runnable examples and the continuous-batching
+  scheduler.
 """
 from __future__ import annotations
 
@@ -133,8 +134,10 @@ class AnalyticEngine:
 
 class ModelEngine:
     """Slot-based engine over a zoo model: jitted prefill into a slot +
-    per-slot vmapped decode (each slot has its own position/kv_len, the
-    requirement for continuous batching).
+    one decode step over all slots, each slot at its own position/kv_len
+    (the requirement for continuous batching): the slots are the batch of
+    one ``lm.decode_step`` with a (n_slots,) position, so each slot's new
+    cache row is written in place, with no loop over the slots.
 
     For an MoE model each step also returns its routing counter, the
     assignments each held expert received in each MoE layer, fetched with
@@ -153,22 +156,10 @@ class ModelEngine:
         self.prefill_routed = None    # (n_moe_layers, E_held) running total
         self._jit_prefill = jax.jit(partial(lm.prefill, cfg=cfg,
                                             routing=True))
-        # vmap decode over the slot axis: cache leaves are (n_layers, B, ...)
-        cache_axes = jax.tree.map(lambda _: 1, self.cache)
-
-        def _one(params, tokens, cache, pos):
-            # vmap strips the slot axis (axis 1 of every cache leaf);
-            # decode_step expects an explicit batch dim -> re-insert B=1
-            cache1 = jax.tree.map(lambda a: a[:, None], cache)
-            logits, new_cache, routed = lm.decode_step(
-                params, cfg, tokens[None], cache1, pos,
-                kv_len=(pos + 1)[None], routing=True)
-            return (logits[0], jax.tree.map(lambda a: a[:, 0], new_cache),
-                    routed)
-
-        self._jit_decode = jax.jit(jax.vmap(
-            _one, in_axes=(None, 0, cache_axes, 0),
-            out_axes=(0, cache_axes, 0)))
+        self._jit_decode = jax.jit(
+            lambda params, tokens, cache, pos: lm.decode_step(
+                params, cfg, tokens, cache, pos, kv_len=pos + 1,
+                routing=True))
 
     def free_slots(self) -> list[int]:
         return [i for i in range(self.n_slots) if not self.active[i]]

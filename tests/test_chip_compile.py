@@ -9,6 +9,7 @@ The topology is described only inside the module fixture below: one
 process at a time may load the TPU library, and only the worker that runs
 this file should. Keep every described-chip compile in this one file.
 """
+import re
 from functools import partial
 
 import jax
@@ -122,4 +123,27 @@ def test_served_expert_layer_compiles_for_v5e(one_chip, tokens):
                         one_chip)).compile()
     out, routed = compiled.out_info
     assert out.shape == (1, tokens, cfg.d_model)
-    assert routed.shape == (20,) and routed.dtype == jnp.int32
+    assert routed.shape == (tokens, 20) and routed.dtype == jnp.int32
+
+
+def test_engine_decode_compiles_to_one_loop_for_v5e(one_chip):
+    """The engine's decode step at minicpm3-4b's published widths (a cut
+    depth), 16 slots of 512: one step over all slots, whose compiled
+    program holds one loop, the layer scan. Each slot's new latent row is
+    written by a select, not by a scatter that XLA expands into a loop
+    over the slots inside every layer."""
+    from repro.configs.base import get_config
+    from repro.models import lm
+    from repro.serving.engine import ModelEngine
+    n_slots, max_len = 16, 512
+    cfg = get_config("minicpm3-4b").replace(n_layers=2, remat=False)
+    params = jax.eval_shape(partial(lm.init_params, cfg=cfg),
+                            jax.random.PRNGKey(0))
+    spec = lambda a: _spec(a.shape, a.dtype, one_chip)
+    eng = ModelEngine(jax.tree.map(spec, params), cfg, n_slots=n_slots,
+                      max_len=max_len)
+    text = eng._jit_decode.lower(
+        eng.params, _spec((n_slots, 1), jnp.int32, one_chip),
+        jax.tree.map(spec, eng.cache),
+        _spec((n_slots,), jnp.int32, one_chip)).compile().as_text()
+    assert len(re.findall(r"^\s*%\S+ = .* while\(", text, re.M)) == 1

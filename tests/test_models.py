@@ -150,6 +150,106 @@ def test_sliding_window_ring_buffer(rng):
                                atol=3e-4, rtol=3e-3)
 
 
+# (arch, overrides, per-row positions): distinct positions of B = 4 rows
+# in a cache of 16; the ring's window of 8 wraps rows 1 and 2
+PER_ROW_POS = {
+    "mla": ("minicpm3-4b", dict(dtype="float32"), [3, 0, 9, 15]),
+    "gqa-bf16": ("qwen3-14b", {}, [3, 0, 9, 15]),
+    "gqa-int8": ("qwen3-14b", dict(dtype="float32", kv_dtype="int8"),
+                 [3, 0, 9, 15]),
+    "ring": ("mixtral-8x7b", dict(dtype="float32", window=8), [3, 11, 20, 7]),
+    "moe": ("deepseek-v2-236b", dict(dtype="float32"), [3, 0, 9, 15]),
+}
+
+
+def _random_cache(cfg, B, L, rng):
+    """A cache full of values, so a row written where it must not be
+    shows; int8 codes dequantize to about unit scale, as k and v do."""
+    def fill(a):
+        if a.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, a.shape), jnp.int8)
+        if a.dtype == jnp.float16:                  # int8 scales
+            return jnp.asarray(rng.uniform(0.5, 2, a.shape) / 127, a.dtype)
+        return jnp.asarray(rng.normal(size=a.shape), a.dtype)
+    return jax.tree.map(fill, lm.init_cache(cfg, B, L))
+
+
+@pytest.mark.parametrize("case", list(PER_ROW_POS))
+def test_decode_step_at_per_row_positions_matches_one_row_steps(case, rng):
+    """decode_step with a (B,) pos, a position per row, is B one-row steps
+    at a scalar pos: the logits agree, each row's new cache entry lands
+    at its own position, every other entry is untouched, and an MoE
+    model's routing counter is each row's own."""
+    arch, over, pos = PER_ROW_POS[case]
+    cfg = get_config(arch).reduced().replace(remat=False, **over)
+    params = lm.init_params(jax.random.PRNGKey(0), cfg)
+    B, Lc = len(pos), lm.cache_len(cfg, 16)
+    cache = _random_cache(cfg, B, 16, rng)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, 1)), jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    kv_len = jnp.minimum(pos + 1, 16)
+    lg, new, routed = lm.decode_step(params, cfg, toks, cache, pos,
+                                     kv_len=kv_len, routing=True)
+    f32 = cfg.dtype == "float32"
+    for b in range(B):
+        one = jax.tree.map(lambda a: a[:, b:b + 1], cache)
+        lg1, new1, routed1 = lm.decode_step(
+            params, cfg, toks[b:b + 1], one, pos[b], kv_len=kv_len[b:b + 1],
+            routing=True)
+        np.testing.assert_allclose(
+            np.asarray(lg[b], np.float32), np.asarray(lg1[0], np.float32),
+            rtol=1e-5 if f32 else 2e-2, atol=1e-5 if f32 else 2e-2)
+        at = int(pos[b]) % Lc
+        for key in new:
+            got, want = np.asarray(new[key][:, b]), np.asarray(new1[key][:, 0])
+            before = np.asarray(cache[key][:, b])
+            rest = np.arange(Lc) != at
+            np.testing.assert_array_equal(got[:, rest], before[:, rest])
+            np.testing.assert_array_equal(want[:, rest], before[:, rest])
+            np.testing.assert_allclose(
+                got[:, at].astype(np.float32), want[:, at].astype(np.float32),
+                rtol=1e-5 if f32 else 1e-2, atol=1e-5 if f32 else 1e-2)
+        if cfg.is_moe:
+            np.testing.assert_array_equal(np.asarray(routed[b]),
+                                          np.asarray(routed1[0]))
+    if cfg.is_moe:
+        n_moe = cfg.n_layers - cfg.first_dense_layers
+        assert routed.shape == (B, n_moe, cfg.n_experts)
+        np.testing.assert_array_equal(np.asarray(routed).sum(axis=-1),
+                                      cfg.top_k)
+    else:
+        assert routed is None
+
+
+@pytest.mark.parametrize("case", list(PER_ROW_POS))
+def test_decode_step_at_a_scalar_position_writes_by_slice_update(case):
+    """A scalar pos, shared by the rows, writes each cache leaf's new row
+    with a slice update, as before (B,) positions existed: its program
+    holds no select over a layer's cache leaf. A (B,) pos writes by such
+    a select, which shows that the search finds one."""
+    arch, over, _ = PER_ROW_POS[case]
+    cfg = get_config(arch).reduced().replace(remat=False, **over)
+    B = 2
+    params = jax.eval_shape(lambda k: lm.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: lm.init_cache(cfg, B, 37))
+    leaf_shapes = {"x".join(map(str, a.shape[1:]))
+                   for a in jax.tree.leaves(cache)}
+    step = jax.jit(lambda p, t, c, q: lm.decode_step(p, cfg, t, c, q))
+
+    def selects_over_a_leaf(pos_shape):
+        text = step.lower(params, jax.ShapeDtypeStruct((B, 1), jnp.int32),
+                          cache, jax.ShapeDtypeStruct(pos_shape, jnp.int32)
+                          ).as_text()
+        return [ln for ln in text.splitlines() if "stablehlo.select" in ln
+                and any(f"tensor<{s}x" in ln for s in leaf_shapes)], text
+
+    sel, text = selects_over_a_leaf(())
+    assert not sel, sel
+    assert "dynamic_update_slice" in text
+    assert selects_over_a_leaf((B,))[0]
+
+
 def test_embedder_unit_norm(rng):
     cfg = get_config("siso-embedder").reduced()
     params = embedder.init_params(jax.random.PRNGKey(0), cfg)

@@ -92,7 +92,8 @@ def test_served_layer_drops_nothing(rng):
     # ulps of the largest output
     np.testing.assert_allclose(np.asarray(out), np.asarray(alone), rtol=1e-5,
                                atol=1e-6 * float(jnp.abs(out).max()))
-    assert int(routed[3]) == 64 and int(routed.sum()) == 64 * 6
+    assert routed.shape == (64, 160)                # per token
+    assert int(routed[:, 3].sum()) == 64 and int(routed.sum()) == 64 * 6
     dropped, _ = L.moe_apply(p, cfg, x)             # training: capacity 8
     assert not np.allclose(np.asarray(dropped), np.asarray(out), atol=1e-3)
 
@@ -113,8 +114,8 @@ def test_the_group_shares_add_up_to_the_whole_layer(rng):
                  for k, v in p.items()}
         out, r = L.moe_serve(share, cfg, x, expert_lo=20 * g)
         total = total + out
-        np.testing.assert_array_equal(np.asarray(r),
-                                      np.asarray(routed[20 * g:20 * g + 20]))
+        np.testing.assert_array_equal(
+            np.asarray(r), np.asarray(routed[:, 20 * g:20 * g + 20]))
     # f32 at width 64: eight partial sums in place of one, a few ulps of
     # the largest output
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole), rtol=1e-5,

@@ -43,6 +43,10 @@ for arch in ["mixtral-8x7b", "deepseek-v2-236b"]:
         y_sm, _ = jax.jit(lambda p, x: L.moe_apply(p, cfg_sm, x))(p, x)
     err = np.abs(np.asarray(y_sm) - np.asarray(y_ref)).max()
     assert err < 1e-4, (arch, err)
+    _, r_ref = L.moe_serve(p, cfg, x)
+    with mesh:
+        _, r_sm = jax.jit(lambda p, x: L.moe_serve(p, cfg_sm, x))(p, x)
+    np.testing.assert_array_equal(np.asarray(r_sm), np.asarray(r_ref))
 print("SM_MOE_OK")
 """
     env = dict(os.environ)

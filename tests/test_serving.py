@@ -254,6 +254,71 @@ def test_scheduler_continuous_batching_matches_sequential(rng):
             pos += 1
 
 
+def _vmapped_decode(lm, cfg, cache):
+    """The engine's former decode: each slot as its own batch of one at a
+    scalar position, vmapped over the slot axis (axis 1 of every cache
+    leaf (n_layers, n_slots, ...))."""
+    import jax
+    cache_axes = jax.tree.map(lambda _: 1, cache)
+
+    def _one(params, tokens, cache, pos):
+        cache1 = jax.tree.map(lambda a: a[:, None], cache)
+        logits, new_cache, routed = lm.decode_step(
+            params, cfg, tokens[None], cache1, pos,
+            kv_len=(pos + 1)[None], routing=True)
+        return (logits[0], jax.tree.map(lambda a: a[:, 0], new_cache),
+                None if routed is None else routed[0])
+
+    return jax.vmap(_one, in_axes=(None, 0, cache_axes, 0),
+                    out_axes=(0, cache_axes, 0))
+
+
+@pytest.mark.parametrize("arch", ["minicpm3-4b", "qwen2.5-14b", "rwkv6-7b",
+                                  "zamba2-7b"],
+                         ids=["mla", "gqa", "rwkv6", "zamba2"])
+def test_engine_batched_decode_matches_vmapped_slots(arch, rng):
+    """The engine's one decode step over all slots gives the tokens of
+    the former formulation, a one-slot step vmapped over the slots, through
+    staggered prefills and a release: slots at different positions, one
+    of them reused. The recurrent kinds (rwkv6; mamba2 with zamba's shared
+    attention) step the same way."""
+    import jax
+    from repro.models import lm
+    from repro.serving.engine import ModelEngine
+    cfg = get_config(arch).reduced().replace(remat=False, dtype="float32")
+    params = lm.init_params(jax.random.PRNGKey(1), cfg)
+    new = ModelEngine(params, cfg, n_slots=3, max_len=32)
+    old = ModelEngine(params, cfg, n_slots=3, max_len=32)
+    old._jit_decode = jax.jit(_vmapped_decode(lm, cfg, old.cache))
+    toks = np.zeros(3, np.int32)
+
+    def prefill(slot, n):
+        prompt = rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+        toks[slot] = new.prefill_into(slot, prompt)
+        assert old.prefill_into(slot, prompt) == toks[slot]
+
+    def decode(steps):
+        for _ in range(steps):
+            got, want = new.decode_active(toks), old.decode_active(toks)
+            active = new.active
+            np.testing.assert_array_equal(got[active], want[active])
+            np.testing.assert_array_equal(new.pos, old.pos)
+            toks[active] = got[active]
+
+    prefill(0, 5)
+    decode(2)
+    prefill(2, 9)
+    decode(3)
+    new.release(0)
+    old.release(0)
+    prefill(0, 3)
+    prefill(1, 7)
+    decode(4)
+    for a, b in zip(jax.tree.leaves(new.cache), jax.tree.leaves(old.cache)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-5)
+
+
 def test_cache_admission_skips_engine(rng):
     import jax
     from repro.core.siso import SISO, SISOConfig
